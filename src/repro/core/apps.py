@@ -11,6 +11,7 @@ plus a shared engine pool factory.
 """
 from __future__ import annotations
 
+import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.core.workflow import APP, EngineSpec, Node
@@ -20,12 +21,18 @@ from repro.engines.model_free import (ChunkerEngine, SearchAPIEngine,
                                       VectorDBEngine)
 
 
-def build_engines(*, seed: int = 0, llm_max_batch: int = 4,
+def build_engines(*, core_llm: str = "tiny-core-llm", dtype=jnp.float32,
+                  seed: int = 0, llm_max_batch: int = 4,
                   emb_max_batch: int = 16, paged_kv: bool = False,
                   kv_block_size: int = 16, chunked_prefill: bool = False,
                   prefill_chunk: int = 128, token_budget=None,
                   prefix_cache: str = "none"):
     """One shared pool (the paper co-locates apps on shared engines).
+    ``core_llm`` names the core LLM's config (``configs.base.get_config``;
+    the tiny stand-in by default, ``chatglm3-6b`` at its published
+    widths on the chip) and ``dtype`` the LLM engines' weights and
+    activations (float32 by default, for the CPU tests; bfloat16 on the
+    chip). ``lite_llm`` and the encoders stay the small models.
     ``paged_kv`` switches the LLM engines to the block-paged KV cache
     (copy-on-write prefix sharing, block-based occupancy/backpressure);
     ``chunked_prefill`` streams prompts through each LLM replica's
@@ -33,21 +40,15 @@ def build_engines(*, seed: int = 0, llm_max_batch: int = 4,
     iterations (stall-free prefill); ``prefix_cache="radix"`` adds the
     global radix-tree prefix cache (any shared block-aligned prompt
     prefix reuses cached KV across queries; requires paged_kv)."""
+    llm = dict(dtype=dtype, paged=paged_kv, block_size=kv_block_size,
+               chunked_prefill=chunked_prefill, prefill_chunk=prefill_chunk,
+               token_budget=token_budget, prefix_cache=prefix_cache)
     return {
-        "core_llm": LLMEngine("core_llm", get_config("tiny-core-llm"),
-                              seed=seed, max_batch=llm_max_batch,
-                              paged=paged_kv, block_size=kv_block_size,
-                              chunked_prefill=chunked_prefill,
-                              prefill_chunk=prefill_chunk,
-                              token_budget=token_budget,
-                              prefix_cache=prefix_cache),
+        "core_llm": LLMEngine("core_llm", get_config(core_llm), seed=seed,
+                              max_batch=llm_max_batch, **llm),
         "lite_llm": LLMEngine("lite_llm", get_config("tiny-lite-llm"),
                               seed=seed + 1, max_batch=llm_max_batch * 2,
-                              paged=paged_kv, block_size=kv_block_size,
-                              chunked_prefill=chunked_prefill,
-                              prefill_chunk=prefill_chunk,
-                              token_budget=token_budget,
-                              prefix_cache=prefix_cache),
+                              **llm),
         "embedding": EmbeddingEngine(max_batch=emb_max_batch),
         "rerank": RerankEngine(max_batch=emb_max_batch),
         "vectordb": VectorDBEngine(),

@@ -1019,9 +1019,17 @@ class Runtime:
                 s.forget(ctx.qid)
 
     def shutdown(self):
+        """Stop every scheduler and decode loop, and wait (up to 10 s
+        each) for the scheduler threads and a pooled scheduler's replica
+        workers to exit: a caller that drops the engines afterwards then
+        frees their device memory."""
         for s in self.scheds.values():
             s.stop()
         for eng in self.engines.values():
             for inst in replicas_of(eng):
                 if hasattr(inst, "stop_decode_loop"):
                     inst.stop_decode_loop()
+        for s in self.scheds.values():
+            for th in [s] + list(getattr(s, "workers", ())):
+                if th.is_alive() and th is not threading.current_thread():
+                    th.join(10.0)

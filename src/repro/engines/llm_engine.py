@@ -19,9 +19,15 @@ stacks or unstacks KV, and occupancy/backpressure are counted in
 allocated blocks (true memory). ``paged=False`` (default) preserves the
 dense per-sequence path.
 
-On TPU the attention inside apply_model would route to the Pallas
-flash_prefill / decode_attention kernels (paged_decode_attention for the
-paged pool); on CPU the XLA take/scatter path is used.
+Attention inside apply_model takes the XLA path (dense cache, or the
+take/scatter view of the paged pool) on every backend; the Pallas
+``verify_attention`` / ``chunk_prefill_attention`` kernels serve the
+paged pool only behind the ``pallas_paged_attn`` /
+``pallas_chunk_prefill`` optflags (models/attention.py).
+
+Each engine lives on one device (the first; ``clone`` places replica i
+on device i): its weights, KV store and step inputs are committed there,
+so a pool's replicas sit one per chip.
 """
 from __future__ import annotations
 
@@ -84,6 +90,7 @@ class LLMEngine(DecodeLoopMixin):
                  token_budget: Optional[int] = None,
                  prefix_cache: str = "none"):
         self.name = name
+        self.device = jax.devices()[0]
         self.cfg = cfg
         self.max_len = max_len
         self.max_batch = max_batch
@@ -121,7 +128,9 @@ class LLMEngine(DecodeLoopMixin):
                 "prefixes live in the refcounted block pool)")
         self.prefix_cache_mode = prefix_cache
         self.tok = HashTokenizer(cfg.vocab_size)
-        self.params = init_params(cfg, jax.random.key(seed), dtype)
+        with jax.default_device(self.device):
+            self.params = self._put(init_params(cfg, jax.random.key(seed),
+                                                dtype))
         self.states: Dict[str, SeqState] = {}
         self.prefix_cache: Dict[str, SeqState] = {}
         self._prefix_toks: Dict[str, list] = {}   # instr -> token list
@@ -137,7 +146,7 @@ class LLMEngine(DecodeLoopMixin):
             self.num_blocks = num_blocks if num_blocks is not None else \
                 1 + max_batch * kvc.blocks_for(max_len, block_size)
             self.alloc = kvc.BlockAllocator(self.num_blocks)
-            self.pool = kvc.init_paged_pool(cfg, self.num_blocks, block_size)
+            self.pool = self._new_pool()
             self._paged_step = self._build_paged_step()
             self._paged_pstep = self._build_paged_prefill_step()
             # block-table width buckets (jit shape reuse), capped at the
@@ -193,13 +202,19 @@ class LLMEngine(DecodeLoopMixin):
         self._reset_batch_cache()
 
     def clone(self, idx: int = 1) -> "LLMEngine":
-        """Pool replica: SHARED weights, tokenizer and compiled steps;
+        """Pool replica ``idx``, placed on ``jax.devices()[idx % n]``:
+        one replica per chip where there are enough chips. SHARED
+        tokenizer and compiled steps, and weights shared with replicas
+        on the same device (another device gets its own copy);
         PER-REPLICA sequence/KV store, lock, occupancy meter and stats.
         The instruction-prefix cache is shared in dense mode (states are
-        portable pytrees) but PER-REPLICA in paged mode (a prefix state's
-        blocks live in one replica's physical pool)."""
+        portable pytrees, moved to the forking replica's device) but
+        PER-REPLICA in paged mode (a prefix state's blocks live in one
+        replica's physical pool)."""
         c = LLMEngine.__new__(LLMEngine)
         c.name = f"{self.name}.r{idx}"
+        devices = jax.devices()
+        c.device = devices[idx % len(devices)]
         c.cfg = self.cfg
         c.max_len = self.max_len
         c.max_batch = self.max_batch
@@ -210,7 +225,8 @@ class LLMEngine(DecodeLoopMixin):
         c.token_budget = self.token_budget
         c.prefix_cache_mode = self.prefix_cache_mode
         c.tok = self.tok
-        c.params = self.params
+        c.params = self.params if c.device == self.device \
+            else c._put(self.params)
         c.states = {}
         c.use_prefix_cache = self.use_prefix_cache
         c._lock = threading.Lock()
@@ -223,7 +239,7 @@ class LLMEngine(DecodeLoopMixin):
             c.prefix_cache = {}
             c._prefix_toks = {}
             c.alloc = kvc.BlockAllocator(self.num_blocks)
-            c.pool = kvc.init_paged_pool(c.cfg, c.num_blocks, c.block_size)
+            c.pool = c._new_pool()
             c._paged_step = self._paged_step
             c._paged_pstep = self._paged_pstep
             c._blk_buckets = self._blk_buckets
@@ -257,6 +273,20 @@ class LLMEngine(DecodeLoopMixin):
         c._slo_block_charge = {}
         c._reset_batch_cache()
         return c
+
+    def _put(self, x):
+        """Commit an array, a host buffer or a pytree to this replica's
+        device (no copy when it is already there)."""
+        return jax.device_put(x, self.device)
+
+    def _new_pool(self):
+        with jax.default_device(self.device):
+            return self._put(kvc.init_paged_pool(self.cfg, self.num_blocks,
+                                                 self.block_size))
+
+    def _new_cache(self):
+        with jax.default_device(self.device):
+            return self._put(kvc.init_cache(self.cfg, 1, self.max_len))
 
     def enable_speculative(self, draft: "LLMEngine" = None, k: int = 4,
                            max_ngram: int = 3):
@@ -450,7 +480,7 @@ class LLMEngine(DecodeLoopMixin):
                             self._decode_reserved[sid] = max(0, resv - got)
                 tables, pos = self._table_batch(states, B, S)
                 logits, self.pool = self._paged_vstep(
-                    self.params, jnp.asarray(toks), self.pool, tables, pos)
+                    self.params, self._put(toks), self.pool, tables, pos)
             finally:
                 self._paged_lock.release()
         else:
@@ -458,7 +488,7 @@ class LLMEngine(DecodeLoopMixin):
             # are discarded), not fresh max_len caches per call
             pad_states = states + self._pad_states(B - len(states))
             cache, pos = self._stack_states(pad_states)
-            logits, cache = self._vstep(self.params, jnp.asarray(toks),
+            logits, cache = self._vstep(self.params, self._put(toks),
                                         cache, pos)
             self._unstack(cache, pad_states)
         return np.asarray(jnp.argmax(logits, axis=-1))[:len(states)]
@@ -483,7 +513,7 @@ class LLMEngine(DecodeLoopMixin):
     def new_state(self):
         if self.paged:
             return PagedSeqState()
-        return SeqState(cache=kvc.init_cache(self.cfg, 1, self.max_len))
+        return SeqState(cache=self._new_cache())
 
     def fork_state(self, st):
         """Copy-on-write fork: paged mode shares every block (refcount
@@ -495,8 +525,8 @@ class LLMEngine(DecodeLoopMixin):
                     self.alloc.incref(b)
                 return PagedSeqState(table=list(st.table), pos=st.pos,
                                      last_token=st.last_token)
-        return SeqState(cache=jax.tree.map(lambda a: a, st.cache),
-                        pos=st.pos, last_token=st.last_token)
+        return SeqState(cache=self._put(st.cache), pos=st.pos,
+                        last_token=st.last_token)
 
     # -- paged block planning ----------------------------------------------
     # (all helpers below require self._paged_lock held)
@@ -577,7 +607,7 @@ class LLMEngine(DecodeLoopMixin):
             tables[i, :n] = s.table[:n]
         pos = np.zeros((B,), np.int32)
         pos[:len(states)] = [s.pos for s in states]
-        return jnp.asarray(tables), jnp.asarray(pos)
+        return self._put(tables), self._put(pos)
 
     def _acquire_with_blocks(self, pairs):
         """Admission backpressure: acquire self._paged_lock WITH enough
@@ -636,7 +666,7 @@ class LLMEngine(DecodeLoopMixin):
     def _stack_states(self, states: List[SeqState]):
         cache = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=1),
                              *[s.cache for s in states])
-        pos = jnp.array([s.pos for s in states], jnp.int32)
+        pos = self._put(np.array([s.pos for s in states], np.int32))
         return cache, pos
 
     def _unstack(self, cache, states: List[SeqState]):
@@ -654,10 +684,10 @@ class LLMEngine(DecodeLoopMixin):
 
     def prefill_batch(self, items):
         """items: list of (state, token_list). Pads to a (B,S) bucket and
-        runs one chunked-prefill step per sequence position offset. The
-        returned per-sequence logits are EXACT: gathered at chunk index
-        len(t)-1, so bucketed (right-padded) prefill matches unpadded
-        prefill token-for-token."""
+        runs one chunked-prefill step per sequence position offset.
+        Returns the per-sequence next-token logits (len(items), vocab),
+        which are EXACT: gathered at chunk index len(t)-1, so bucketed
+        (right-padded) prefill matches unpadded prefill token-for-token."""
         self._fault("prefill")
         t0 = time.time()
         B = _bucket(len(items), BUCKETS_B)
@@ -675,6 +705,7 @@ class LLMEngine(DecodeLoopMixin):
             self.stats["prefill_tokens"] += sum(len(t) for _, t in items)
             self.stats["calls"] += 1
             self.stats["busy_s"] += time.time() - t0
+        return logits[:len(items)]
 
     def prefill_chunked(self, items, chunk: Optional[int] = None):
         """Resumable chunked prefill: advance every item's prompt by at
@@ -704,8 +735,8 @@ class LLMEngine(DecodeLoopMixin):
         pad_states = states + [self.new_state()
                                for _ in range(B - len(states))]
         cache, pos = self._stack_states(pad_states)
-        logits, cache = self._pstep(self.params, jnp.asarray(toks),
-                                    cache, pos, jnp.asarray(last_idx))
+        logits, cache = self._pstep(self.params, self._put(toks),
+                                    cache, pos, self._put(last_idx))
         self._unstack(cache, pad_states)
         return logits
 
@@ -732,8 +763,8 @@ class LLMEngine(DecodeLoopMixin):
         with the write range already prepared/COW-resolved)."""
         tables, pos = self._table_batch(states, B, S)
         logits, self.pool = self._paged_pstep(
-            self.params, jnp.asarray(toks), self.pool, tables, pos,
-            jnp.asarray(last_idx))
+            self.params, self._put(toks), self.pool, tables, pos,
+            self._put(last_idx))
         return logits
 
     def decode_batch(self, items, on_chunk=None):
@@ -760,7 +791,8 @@ class LLMEngine(DecodeLoopMixin):
             pad_states = states + [self.new_state()
                                    for _ in range(B - len(states))]
             cache, pos = self._stack_states(pad_states)
-            cur = jnp.array([[s.last_token] for s in pad_states], jnp.int32)
+            cur = self._put(np.array([[s.last_token] for s in pad_states],
+                                     np.int32))
             outs = [[] for _ in pad_states]
             emitted = [0] * len(items)
             for t in range(n_max):
@@ -805,10 +837,10 @@ class LLMEngine(DecodeLoopMixin):
                 states, B, [n for _, n in items], pad_new=1)
             limit = np.ones((B,), np.int32)
             limit[:len(states)] = [s.pos + n for s, n in items]
-            limit = jnp.asarray(limit)
+            limit = self._put(limit)
             cur = np.ones((B, 1), np.int32)
             cur[:len(states), 0] = [s.last_token for s in states]
-            cur = jnp.asarray(cur)
+            cur = self._put(cur)
             outs = [[] for _ in range(B)]
             emitted = [0] * len(items)
             for t in range(n_max):
@@ -1130,7 +1162,7 @@ class LLMEngine(DecodeLoopMixin):
             # write the shared batch cache back first (residency is
             # changing), then drop this sequence's KV arrays
             self._flush_batch_cache()
-            st.cache = kvc.init_cache(self.cfg, 1, self.max_len)
+            st.cache = self._new_cache()
         st.pos = 0
         st.last_token = 1                # replay re-derives it
         seq.slo_preempted = True
@@ -1216,7 +1248,8 @@ class LLMEngine(DecodeLoopMixin):
         decoding enabled, sequences with enough remaining budget advance
         by a whole verified draft chunk per pass (the loop counts their
         emitted tokens); the rest — and everything, with it disabled —
-        take the legacy single-token step."""
+        take the single-token step, whose next-token logits
+        (len(seqs), vocab) are returned."""
         self._fault("decode")
         if self.slo is not None:
             for r in seqs:
@@ -1256,7 +1289,7 @@ class LLMEngine(DecodeLoopMixin):
                 cur = np.ones((B, 1), np.int32)
                 cur[:len(states), 0] = [s.last_token for s in states]
                 logits, self.pool = self._paged_step(
-                    self.params, jnp.asarray(cur), self.pool, tables, pos)
+                    self.params, self._put(cur), self.pool, tables, pos)
         else:
             key = tuple(id(r) for r in seqs)
             if key != self._batch_key:
@@ -1266,8 +1299,8 @@ class LLMEngine(DecodeLoopMixin):
                 self._batch_cache, self._batch_pos = \
                     self._stack_states(self._batch_states)
                 self._batch_key = key
-            cur = jnp.array([[s.last_token] for s in self._batch_states],
-                            jnp.int32)
+            cur = self._put(np.array(
+                [[s.last_token] for s in self._batch_states], np.int32))
             logits, self._batch_cache = self._step(
                 self.params, cur, self._batch_cache, self._batch_pos)
             self._batch_pos = self._batch_pos + 1
@@ -1282,6 +1315,7 @@ class LLMEngine(DecodeLoopMixin):
             self.stats["decode_tokens"] += len(seqs)
             self.stats["decode_iters"] += 1
             self.stats["busy_s"] += time.time() - t0
+        return logits[:len(seqs)]
 
     # -- high-level ops used by the schedulers ------------------------------
     def _match_prefix_locked(self, toks):
@@ -1583,7 +1617,9 @@ class LLMEngine(DecodeLoopMixin):
             new_st = PagedSeqState(table=dst_table, pos=st.pos,
                                    last_token=st.last_token)
         else:
-            # dense states are portable pytrees — adopt the object
+            # dense states are portable pytrees: adopt the object, its
+            # cache moved onto this replica's device
+            st.cache = self._put(st.cache)
             new_st = st
         with self._lock:
             self.states[sid] = new_st

@@ -35,7 +35,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -2.0e38
+_MAX_Q_ROWS = 2048
 
 
 def _chunk_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
@@ -101,7 +104,7 @@ def _chunk_kernel(tbl_ref, start_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
 
 def chunk_prefill_attention(q, k_pool, v_pool, block_tables, start, *,
                             window=None, cap=None, scale=None, bq: int = 128,
-                            interpret: bool = True):
+                            interpret=None):
     """Chunked-prefill attention over the paged block pool.
 
     q (B, Sq, H, hd): the prompt chunk's queries, row i of sequence b at
@@ -119,7 +122,12 @@ def chunk_prefill_attention(q, k_pool, v_pool, block_tables, start, *,
     maxblk = block_tables.shape[1]
     if scale is None:
         scale = hd ** -0.5
-    bq = min(bq, Sq)
+    # VMEM: a grid step holds about eight f32 tiles of bq*H rows by 128
+    # lanes (accumulator, lane-padded max and denominator, scores,
+    # probabilities, upcast queries) beside the double-buffered q/o
+    # blocks; bq*H <= _MAX_Q_ROWS keeps that inside a v5e's 16 MiB of
+    # scoped VMEM (32 heads at bq=128 overflow it)
+    bq = min(bq, Sq, max(8, _MAX_Q_ROWS // H))
     if Sq % bq != 0:
         bq = Sq                      # engine buckets divide evenly; odd
     #                                  test shapes fall back to one block
@@ -150,6 +158,6 @@ def chunk_prefill_attention(q, k_pool, v_pool, block_tables, start, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_tables.astype(jnp.int32), start.astype(jnp.int32), q, kh, vh)
     return out

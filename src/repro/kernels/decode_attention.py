@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -2.0e38
 
 
@@ -105,7 +107,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 def decode_attention(q, k, v, length, *, window=None, cap=None, scale=None,
-                     bk: int = 128, interpret: bool = True):
+                     bk: int = 128, interpret=None):
     """q (B,H,hd); k,v (B,T,K,hd); length (B,) int32 valid lengths.
     Returns (B,H,hd)."""
     B, H, hd = q.shape
@@ -139,7 +141,7 @@ def decode_attention(q, k, v, length, *, window=None, cap=None, scale=None,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(length.astype(jnp.int32), q, kh, vh)
     return out
 
@@ -231,7 +233,7 @@ def _verify_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
 
 def verify_attention(q, k_pool, v_pool, block_tables, length, *,
                      window=None, cap=None, scale=None,
-                     interpret: bool = True):
+                     interpret=None):
     """Speculative-verification attention over the paged pool.
 
     q (B, Sq, H, hd): the drafted chunk's queries (Sq = draft_k + 1);
@@ -273,14 +275,14 @@ def verify_attention(q, k_pool, v_pool, block_tables, length, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_tables.astype(jnp.int32), length.astype(jnp.int32), q, kh, vh)
     return out
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, length, *,
                            window=None, cap=None, scale=None,
-                           interpret: bool = True):
+                           interpret=None):
     """q (B,H,hd); k_pool,v_pool (num_blocks, block_size, K, hd) shared
     pools; block_tables (B, maxblk) int32 physical block ids per logical
     block; length (B,) int32 valid lengths. Returns (B,H,hd)."""
@@ -317,6 +319,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, length, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_tables.astype(jnp.int32), length.astype(jnp.int32), q, kh, vh)
     return out

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -2.0e38
 
 
@@ -74,7 +76,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_prefill(q, k, v, *, prefix_len: int = 0, window=None, cap=None,
                   scale=None, total_len=None, bq: int = 128, bk: int = 128,
-                  interpret: bool = True):
+                  interpret=None):
     """q (B, Sq, H, hd); k, v (B, T, K, hd) — the cache buffer with the
     chunk already written at [prefix_len, prefix_len+Sq).
     Returns o (B, Sq, H, hd).
@@ -117,6 +119,6 @@ def flash_prefill(q, k, v, *, prefix_len: int = 0, window=None, cap=None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qh, kh, vh)
     return jnp.moveaxis(out, 1, 2)  # (B, Sq, H, hd)

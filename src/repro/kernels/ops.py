@@ -1,13 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (the Pallas body
-executed in Python for correctness validation); on TPU set
-``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) to compile the real
-Mosaic kernels.
+``interpret=None`` (the default) resolves through
+``repro.kernels.resolve_interpret``: the kernels compile for Mosaic on a
+TPU and run in interpret mode on any other backend (the CPU tests). An
+explicit ``interpret=True`` on a TPU is an error.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -22,19 +21,11 @@ from repro.kernels.flash_prefill import flash_prefill as _prefill
 from repro.kernels.rwkv6_scan import rwkv6_scan as _rwkv
 
 
-def default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("prefix_len", "window", "cap", "scale",
                                    "total_len", "bq", "bk", "interpret"))
 def flash_prefill(q, k, v, *, prefix_len=0, window=None, cap=None,
                   scale=None, total_len=None, bq=128, bk=128,
                   interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
     return _prefill(q, k, v, prefix_len=prefix_len, window=window, cap=cap,
                     scale=scale, total_len=total_len, bq=bq, bk=bk,
                     interpret=interpret)
@@ -44,7 +35,6 @@ def flash_prefill(q, k, v, *, prefix_len=0, window=None, cap=None,
                                    "interpret"))
 def decode_attention(q, k, v, length, *, window=None, cap=None, scale=None,
                      bk=128, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
     return _decode(q, k, v, length, window=window, cap=cap, scale=scale,
                    bk=bk, interpret=interpret)
 
@@ -53,7 +43,6 @@ def decode_attention(q, k, v, length, *, window=None, cap=None, scale=None,
 def paged_decode_attention(q, k_pool, v_pool, block_tables, length, *,
                            window=None, cap=None, scale=None,
                            interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
     return _paged_decode(q, k_pool, v_pool, block_tables, length,
                          window=window, cap=cap, scale=scale,
                          interpret=interpret)
@@ -64,7 +53,6 @@ def verify_attention(q, k_pool, v_pool, block_tables, length, *,
                      window=None, cap=None, scale=None, interpret=None):
     """Speculative-verification attention (paged, q_len = draft_k + 1);
     q_len == 1 reduces to paged_decode_attention."""
-    interpret = default_interpret() if interpret is None else interpret
     return _verify(q, k_pool, v_pool, block_tables, length, window=window,
                    cap=cap, scale=scale, interpret=interpret)
 
@@ -77,7 +65,6 @@ def chunk_prefill_attention(q, k_pool, v_pool, block_tables, start, *,
     """Chunked-prefill attention over the paged pool (q at absolute
     positions start[b] + i); Sq == 1 at start = length - 1 reduces to
     paged_decode_attention."""
-    interpret = default_interpret() if interpret is None else interpret
     return _chunk_prefill(q, k_pool, v_pool, block_tables, start,
                           window=window, cap=cap, scale=scale, bq=bq,
                           interpret=interpret)
@@ -85,5 +72,4 @@ def chunk_prefill_attention(q, k_pool, v_pool, block_tables, start, *,
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_scan(r, k, v, w, u, state0, *, chunk=64, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
     return _rwkv(r, k, v, w, u, state0, chunk=chunk, interpret=interpret)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sf_ref,
             st_ref, *, chunk):
@@ -50,7 +52,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sf_ref,
 
 
 def rwkv6_scan(r, k, v, w, u, state0, *, chunk: int = 64,
-               interpret: bool = True):
+               interpret=None):
     """r,k,v,w (B,S,H,hd); u (H,hd); state0 (B,H,hd,hd) fp32.
     Returns (y (B,S,H,hd) fp32, final_state (B,H,hd,hd) fp32)."""
     B, S, H, hd = r.shape
@@ -77,6 +79,6 @@ def rwkv6_scan(r, k, v, w, u, state0, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, w, u, state0)
     return y, sf

@@ -110,11 +110,7 @@ def run_case(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         print(f"[dryrun] {tag}: FAILED {type(e).__name__}: {e}")
         return rec
 
-    # jax < 0.5 returns a one-element list of per-program dicts; newer
-    # versions return the dict directly.
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     mem_rec = {}
     if mem is not None:

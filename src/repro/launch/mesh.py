@@ -9,18 +9,26 @@ state (smoke tests must keep seeing 1 device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # the model code places activations with sharding hints (GSPMD), so
+    # every axis is Auto; jax.make_mesh defaults to Explicit axes, under
+    # which a gather from the vocab-sharded embedding is refused
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (possibly forced-host) devices exist —
     used by distributed correctness tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants (roofline denominators)

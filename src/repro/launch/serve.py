@@ -3,17 +3,24 @@ on the engine pool and serve queries.
 
   PYTHONPATH=src python -m repro.launch.serve --app advanced_rag \
       --queries 4 [--sim] [--scheme Teola|LlamaDist-TO|...] \
-      [--llm-instances 2] [--streaming]
+      [--llm-instances 2] [--streaming] \
+      [--core-llm chatglm3-6b --dtype bfloat16]
 
+The process exits non-zero when any query fails or does not finish
+within QUERY_TIMEOUT seconds. --core-llm names the core LLM's config
+(default the tiny CPU stand-in) and --dtype the LLM engines' dtype
+(default float32; bfloat16 on the chip); both apply to the real engines.
 --llm-instances N puts each LLM engine behind an EnginePool of N replicas
-(shared weights, per-replica KV stores; fused batches are routed to the
-least-loaded replica). --streaming enables decode->downstream chunk
-pipelining; --continuous-batching dispatches decodes into each replica's
-persistent decode loop (iteration-level continuous batching) instead of
-run-to-completion batches (both Teola scheme only). --paged-kv carves
-each replica's KV cache into refcounted token blocks (copy-on-write
-instruction-prefix sharing, block-table indexed decode, occupancy and
-router backpressure counted in allocated blocks). --speculative enables
+(per-replica KV stores; replica i is placed on device i mod the device
+count, with its own copy of the weights on its own device; fused batches
+are routed to the least-loaded replica). --streaming enables
+decode->downstream chunk pipelining; --continuous-batching dispatches
+decodes into each replica's persistent decode loop (iteration-level
+continuous batching) instead of run-to-completion batches (both Teola
+scheme only). --paged-kv carves each replica's KV cache into refcounted
+token blocks (copy-on-write instruction-prefix sharing, block-table
+indexed decode, occupancy and router backpressure counted in allocated
+blocks). --speculative enables
 draft-verify speculative decoding on core_llm (--draft-k tokens drafted
 per target verification step; --spec-drafter picks the model-free
 prompt-lookup drafter or the co-located lite_llm replica pairing);
@@ -74,14 +81,19 @@ layer absent.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.apps import ALL_APPS, build_engines
 from repro.core.engine_pool import build_pools, disaggregate_pools
 from repro.core.teola import AutoGenLike, LlamaDist, LlamaDistPC, Teola
+from repro.launch.device import startup
 from repro.training.data import doc_corpus
+
+QUERY_TIMEOUT = 600.0      # seconds to wait for each query
 
 SCHEMES = {
     "Teola": (Teola, "topo"),
@@ -95,6 +107,11 @@ SCHEMES = {
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--app", default="advanced_rag", choices=ALL_APPS)
+    ap.add_argument("--core-llm", default="tiny-core-llm",
+                    help="config name of the core LLM (real engines)")
+    ap.add_argument("--dtype", default="float32",
+                    choices=("float32", "bfloat16"),
+                    help="dtype of the LLM engines (real engines)")
     ap.add_argument("--scheme", default="Teola", choices=SCHEMES)
     ap.add_argument("--queries", type=int, default=4)
     ap.add_argument("--rate", type=float, default=2.0)
@@ -367,11 +384,11 @@ def validate_args(ap: argparse.ArgumentParser, args) -> None:
         if args.encoder_instances is not None else 1
 
 
-def main():
-    ap = build_parser()
-    args = ap.parse_args()
-    validate_args(ap, args)
-
+def build_serving(args) -> dict:
+    """Engines, optional policy layers and the orchestrator for
+    validated ``args``: everything the launcher stands up before its
+    first query. Returns a dict with ``engines``, ``orch`` and the armed
+    ``ft`` / ``injector`` / ``overload`` (None when off)."""
     if args.sim:
         from repro.engines.sim_engines import build_sim_engines
         engines = build_sim_engines(llm_instances=args.llm_instances,
@@ -387,7 +404,9 @@ def main():
                                     decode_replicas=args.decode_replicas,
                                     encoder_instances=args.encoder_instances)
     else:
-        engines = build_engines(paged_kv=args.paged_kv,
+        engines = build_engines(core_llm=args.core_llm,
+                                dtype=getattr(jnp, args.dtype),
+                                paged_kv=args.paged_kv,
                                 chunked_prefill=args.chunked_prefill,
                                 prefill_chunk=args.prefill_chunk,
                                 token_budget=args.token_budget,
@@ -449,15 +468,24 @@ def main():
                    fault_tolerance=ft, overload=overload)
     else:
         orch = cls(app, engines, policy=policy)
+    return {"engines": engines, "orch": orch, "ft": ft,
+            "injector": injector, "overload": overload}
 
-    docs = doc_corpus(2)
-    print(f"[serve] {args.app} via {args.scheme} "
-          f"({'sim' if args.sim else 'real'} engines); warmup...")
-    orch.query({"question": "warmup question", "docs": docs}, timeout=600)
 
+def warmup(orch, docs):
+    """One query before the timed ones (compiles every engine's first
+    bucket). Returns its context."""
+    ctx = orch.submit({"question": "warmup question", "docs": docs})
+    ctx.done.wait(QUERY_TIMEOUT)
+    return ctx
+
+
+def run_queries(orch, args, docs) -> list:
+    """Submit ``args.queries`` queries with exponential gaps at
+    ``args.rate`` per second (seeded) and wait up to ``QUERY_TIMEOUT``
+    seconds for each. Returns their contexts."""
     rng = np.random.default_rng(0)
     ctxs = []
-    t0 = time.time()
     for i in range(args.queries):
         q = {"question": f"what is fact {i} about optics", "docs": docs}
         if args.slo_sched or args.overload_control:
@@ -470,14 +498,48 @@ def main():
             ctxs.append(orch.submit(q))
         time.sleep(float(rng.exponential(1.0 / args.rate)))
     for c in ctxs:
-        c.done.wait(600)
+        c.done.wait(QUERY_TIMEOUT)
+    return ctxs
+
+
+def query_failures(ctxs) -> list:
+    """``(index, reason)`` for every query that failed or never
+    finished."""
+    out = []
+    for i, c in enumerate(ctxs):
+        if not c.done.is_set():
+            out.append((i, "did not finish"))
+        elif c.error is not None:
+            out.append((i, f"{type(c.error).__name__}: {c.error}"))
+    return out
+
+
+def main(argv=None) -> int:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    validate_args(ap, args)
+    startup("serve")
+    served = build_serving(args)
+    engines, orch = served["engines"], served["orch"]
+    ft, injector = served["ft"], served["injector"]
+    overload = served["overload"]
+
+    docs = doc_corpus(2)
+    print(f"[serve] {args.app} via {args.scheme} "
+          f"({'sim' if args.sim else 'real'} engines); warmup...")
+    warm = warmup(orch, docs)
+    failures = [("warmup", r) for _, r in query_failures([warm])]
+    t0 = time.time()
+    ctxs = run_queries(orch, args, docs) if not failures else []
     wall = time.time() - t0
-    lats = [c.latency for c in ctxs if c.t_done]
-    errs = [c for c in ctxs if c.error is not None]
-    print(f"[serve] {len(lats)}/{args.queries} queries in {wall:.1f}s; "
-          f"avg latency {np.mean(lats) * 1000:.0f}ms "
-          f"p90 {np.percentile(lats, 90) * 1000:.0f}ms"
-          + (f"; {len(errs)} failed" if errs else ""))
+    failures += query_failures(ctxs)
+    lats = [c.latency for c in ctxs if c.t_done and c.error is None]
+    print(f"[serve] {len(lats)}/{args.queries} queries in {wall:.1f}s"
+          + (f"; avg latency {np.mean(lats) * 1000:.0f}ms "
+             f"p90 {np.percentile(lats, 90) * 1000:.0f}ms" if lats else "")
+          + (f"; {len(failures)} failed" if failures else ""))
+    for i, reason in failures:
+        print(f"[serve] query {i} failed: {reason}")
     if ft is not None:
         for s in orch.runtime.scheds.values():
             mgr = getattr(s, "ftmgr", None)
@@ -509,7 +571,8 @@ def main():
                     bad += rep["bad_free"]
         print(f"[serve] kv audit: leaked={leaked} bad_free={bad}")
     orch.shutdown()
+    return 1 if failures else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -26,17 +26,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models.common import act_fn, dense_init, split_keys
 from repro.models.sharding import active_mesh, hint
 
-if hasattr(jax, "shard_map"):                     # jax >= 0.6
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                             # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _sm_legacy
-
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return _sm_legacy(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
 
 def init_moe_params(key, cfg, dtype):
     mo = cfg.moe
@@ -210,10 +199,10 @@ def routed_ep(cfg, p, x2d, mesh):
 
     w_specs = (P(ep_sp, fsdp_sp, None), P(ep_sp, fsdp_sp, None),
                P(ep_sp, None, fsdp_sp))
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(None, None)) + w_specs,
-        out_specs=(tok_spec, P()),
+        out_specs=(tok_spec, P()), check_vma=False,
     )(x2d, p["router"], w_gate, w_up, w_down)
     return out, aux
 

@@ -7,6 +7,7 @@ Teola's Partial/Full Prefilling), and decode (S==1).
 """
 from __future__ import annotations
 
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -55,7 +56,14 @@ def init_layer_elem(key, cfg: ModelConfig, spec: LayerSpec, dtype):
     return p
 
 
+@partial(jax.jit, static_argnames=("cfg", "dtype"))
 def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
+    """Seeded parameter tree. Built under jit so that each float32 draw
+    fuses with its cast to ``dtype``: no float32 copy of a stacked
+    weight is materialised (at ChatGLM3-6B widths the stacked ``w_down``
+    alone would be a 6.3 GB float32 transient). The values are an eager
+    build's, up to one rounding: the compiler folds a draw's scale into
+    ``normal``'s own sqrt(2) factor."""
     ks = split_keys(key, 3 + len(cfg.stages))
     params = {"embed": embed_init(ks[0], (cfg.vocab_size, cfg.d_model),
                                   dtype),
@@ -229,3 +237,39 @@ def apply_model(cfg: ModelConfig, params, inputs, cache=None, pos=0, *,
     logits = softcap(logits, cfg.final_logit_softcap)
     logits = hint(logits, "batch", None, "model")
     return logits, new_cache, aux_total
+
+
+# ---------------------------------------------------------------------------
+# float32 reference
+
+
+@partial(jax.jit, static_argnames=("cfg", "spec"))
+def _reference_layer(cfg, spec, elem, r, x):
+    p = jax.tree.map(lambda a: a[r].astype(jnp.float32), elem)
+    x, _, _ = apply_layer(cfg, spec, p, x, None, 0, q_block=x.shape[1])
+    return x
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def _reference_head(cfg, params, x):
+    x = rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps)
+    head = (params["embed"].T if cfg.tie_embeddings
+            else params["lm_head"])
+    return softcap(x @ head.astype(jnp.float32), cfg.final_logit_softcap)
+
+
+def reference_logits(cfg: ModelConfig, params, tokens):
+    """Cache-free float32 forward of ``tokens`` (B, S) at the highest
+    matmul precision: logits (B, S, vocab) to check the serving paths
+    against (no cache, no paging, no buckets, no kernels). Weights are
+    upcast one layer at a time, so a model whose float32 copy would not
+    fit its device can still be checked there."""
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"][tokens].astype(jnp.float32)
+        if cfg.embed_scale:
+            x = x * jnp.float32(cfg.d_model ** 0.5)
+        for si, st in enumerate(cfg.stages):
+            for r in range(st.repeat):
+                for spec, elem in zip(st.pattern, params["stages"][si]):
+                    x = _reference_layer(cfg, spec, elem, r, x)
+        return _reference_head(cfg, params, x)

@@ -716,8 +716,12 @@ def gather_pool_blocks(pool, blocks):
 
 def scatter_pool_blocks(pool, stage, blocks):
     """Write a staged pytree (from ``gather_pool_blocks``) into slots
-    ``blocks`` of ``pool``. CAUTION: ``pool``'s buffers are donated —
-    callers must rebind to the returned pool. No refcount side effects."""
+    ``blocks`` of ``pool``. The staged blocks are first put on the
+    destination pool's device (replicas of one pool may sit on different
+    chips). CAUTION: ``pool``'s buffers are donated — callers must rebind
+    to the returned pool. No refcount side effects."""
+    stage = jax.tree.map(lambda s, a: jax.device_put(s, a.sharding),
+                         stage, pool)
     return _scatter_blocks_jit(pool, stage, jnp.asarray(blocks, jnp.int32))
 
 

@@ -34,7 +34,8 @@ toks = jax.random.randint(jax.random.key(1), (4, 33), 0, cfg.vocab_size)
 l_single, _ = jax.jit(lambda p, t: next_token_loss(
     cfg, p, t, compute_dtype=jnp.float32, q_block=64))(params, toks)
 
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(data=4, model=2)
 from repro.launch.shard_rules import tree_shardings
 params_sh = jax.device_put(params, tree_shardings(params, mesh))
 toks_sh = jax.device_put(toks, NamedSharding(mesh, P('data', None)))
@@ -134,7 +135,8 @@ cache = init_cache(cfg, 8, 32)
 l1, cache1, _ = apply_model(cfg, params, toks[:, :15], cache, 0)
 l1d, _, _ = apply_model(cfg, params, toks[:, 15:16], cache1, 15)
 
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(data=4, model=2)
 params_sh = jax.device_put(params, tree_shardings(params, mesh))
 def csh(path, leaf):
     import jax.tree_util as jtu
