@@ -24,7 +24,9 @@ QUESTIONS = [
 def main(n=6):
     engines = build_engines()
     app = advanced_rag(engines)
-    teola = Teola(app, engines)
+    # decodes run in the core LLM's continuous loop, whose phases are
+    # printed at the end
+    teola = Teola(app, engines, continuous_batching=True)
     docs = doc_corpus(2)
 
     print("warmup...")
@@ -49,10 +51,13 @@ def main(n=6):
           f"p50={np.percentile(lats, 50) * 1000:.0f}ms "
           f"max={np.max(lats) * 1000:.0f}ms")
     llm = engines["core_llm"]
-    print(f"core LLM engine: {llm.stats['calls']} batched calls, "
-          f"{llm.stats['prefill_tokens']} prefill tokens, "
-          f"{llm.stats['decode_tokens']} decoded tokens, "
-          f"busy {llm.stats['busy_s']:.1f}s")
+    c = llm.counters()
+    print(f"core LLM engine: {c['calls']} batched calls, "
+          f"{c['prefill_tokens']} prefill tokens, "
+          f"{c['decode_tokens']} decoded tokens")
+    print("continuous-loop phases (s): " + ", ".join(
+        f"{k[:-2]} {c[k]:.2f}" for k in ("plan_s", "prepare_s", "launch_s",
+                                        "wait_s", "readback_s", "emit_s")))
     sched = teola.runtime.scheds["core_llm"]
     sizes = [s for s, _ in sched.batches]
     print(f"LLM batch sizes formed by topology-aware batching: "

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import inspect
 import threading
+import time
 from typing import List
 
 import numpy as np
@@ -422,7 +423,8 @@ def submit_prefill_task(engine, task, done, on_fail=None, ft=None):
     payload = _prefill_payload(prim, ctx)
 
     if not payload:                      # zero-item prefill: parity with
-        for k in prim.produces:          # the batch path's empty span
+        ctx.note_start(prim.pid, time.time())   # the batch path's
+        for k in prim.produces:                 # empty span
             store[k] = True
         if ft is not None:
             ft.settle()
@@ -450,6 +452,7 @@ def submit_prefill_task(engine, task, done, on_fail=None, ft=None):
     def job_done(j, job):
         if ft is not None and ft.cancelled:
             return                       # deadline already failed the task
+        ctx.note_start(prim.pid, job.t_start or job.t_done)
         with lock:
             if completed[j]:
                 return                   # duplicate (job was recovered)
@@ -536,7 +539,8 @@ def submit_decode_task(engine, task, done, on_fail=None, ft=None):
     entries = decode_entries(prim, ctx)  # (sid, max_new) per sequence
 
     if not entries:                      # zero-item decode: parity with
-        _write_decode_outputs(task, [])  # the batch path's empty span
+        ctx.note_start(prim.pid, time.time())   # the batch path's
+        _write_decode_outputs(task, [])         # empty span
         if ft is not None:
             ft.settle()
         done(task)
@@ -577,6 +581,7 @@ def submit_decode_task(engine, task, done, on_fail=None, ft=None):
     def seq_done(j, seq):
         if ft is not None and ft.cancelled:
             return                       # deadline already failed the task
+        ctx.note_start(prim.pid, seq.t_admit or seq.t_done)
         with lock:
             if completed[j]:
                 return                   # duplicate (seq was recovered)

@@ -76,7 +76,10 @@ class QueryContext:
         # no deadline). The FT watchdog, SLO urgency test and the
         # degradation ladder all derive their clocks from this one value.
         self.deadline: Optional[float] = None
-        self.node_spans: Dict[str, tuple] = {}     # pid -> (t0, t1)
+        # pid -> (ready, done): from when every input was there
+        self.node_spans: Dict[str, tuple] = {}
+        # pid -> when an engine began it (the earliest of its sequences)
+        self.node_start: Dict[str, float] = {}
         self.sids: set = set()
         self.lock = threading.Lock()
         self.error: Optional[Exception] = None
@@ -86,6 +89,12 @@ class QueryContext:
     @property
     def latency(self):
         return (self.t_done or time.time()) - self.t_submit
+
+    def note_start(self, pid: str, t: float):
+        """An engine began primitive ``pid`` at ``t``; the earliest
+        stamp wins."""
+        with self.lock:
+            self.node_start[pid] = min(t, self.node_start.get(pid, t))
 
     def result(self, timeout=120):
         self.done.wait(timeout)
@@ -105,6 +114,13 @@ class NodeTask:
     @property
     def depth(self):
         return self.prim.depth
+
+
+def _note_started(batch: List[NodeTask]):
+    """Stamp ``node_start`` for a batch an engine is about to run."""
+    now = time.time()
+    for t in batch:
+        t.ctx.note_start(t.prim.pid, now)
 
 
 def _fail_batch(batch: List[NodeTask], e: Exception):
@@ -287,6 +303,7 @@ class EngineScheduler(threading.Thread):
             self.batches.append((sum(t.prim.num_requests for t in batch),
                                  batch[0].prim.op))
             try:
+                _note_started(batch)
                 self.executor(self.engine, batch)
             except Exception as e:  # noqa: BLE001
                 _fail_batch(batch, e)
@@ -681,6 +698,7 @@ class PooledEngineScheduler(threading.Thread):
         op = batch[0].prim.op
         ov = self.overload
         delay = self._hedge_delay(batch)
+        _note_started(batch)
         if delay is None:
             t0 = time.time()
             self.executor(worker.engine, batch)
@@ -930,6 +948,7 @@ class Runtime:
     def _dispatch(self, prim: Primitive, ctx: QueryContext):
         ctx.node_spans.setdefault(prim.pid, (time.time(), None))
         if prim.engine == "control":
+            ctx.note_start(prim.pid, time.time())
             self._run_control(prim, ctx)
             self._complete_node(prim, ctx)
             return

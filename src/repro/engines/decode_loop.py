@@ -36,13 +36,35 @@ slot accounting (mirrored into the engine via the optional
 engine forwards to its ``OccupancyMeter``), the prefill token-budget
 admission, and completion signaling. Both the real ``LLMEngine`` and
 the latency-profile ``SimLLMEngine`` drive the same loop.
+
+Each pass is timed in phases (``phase``): the loop's own planning
+(``llm.loop.plan``) and emission (``llm.loop.emit``), and inside the
+real engine's step its preparation, launch, wait for the first sampled
+token and read-back (``llm.step.*``). Every phase is a host span on the
+profiler's clock and a ``<phase>_s`` counter in the engine's ``stats``.
 """
 from __future__ import annotations
 
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager
 from typing import Callable, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+
+@contextmanager
+def phase(acc: dict, name: str, **args):
+    """Host span ``name`` (with ``args``) on the profiler's clock, inert
+    when no trace is being taken; its seconds add to
+    ``acc["<last word of name>_s"]``, which the caller adds to the
+    engine's ``stats`` once per step."""
+    t = time.perf_counter()
+    with TraceAnnotation(name, **args):
+        yield
+    key = name.rsplit(".", 1)[-1] + "_s"
+    acc[key] = acc.get(key, 0.0) + time.perf_counter() - t
 
 
 class DecodeSeq:
@@ -125,6 +147,7 @@ class PrefillJob:
         self.done = threading.Event()
         self.t_submit = time.time()
         self.t_progress = time.time()       # last time a chunk landed
+        self.t_start: Optional[float] = None   # its first chunk's step began
         self.t_done: Optional[float] = None
 
     def remaining(self) -> int:
@@ -304,17 +327,21 @@ class ContinuousDecodeLoop(threading.Thread):
                 room -= n
         return items
 
-    def _note_prefill_progress(self, pitems, cursors_before) -> int:
+    def _note_prefill_progress(self, pitems, cursors_before,
+                               t_step: float) -> int:
         """Account chunks the engine landed this pass (it may decline a
         planned chunk — e.g. paged pool momentarily out of unreserved
         blocks — in which case the job just stays queued); evict jobs
-        whose prompt is fully resident. Returns tokens landed."""
+        whose prompt is fully resident. ``t_step`` is when this pass's
+        step began. Returns tokens landed."""
         landed = 0
         finished = []
         for (job, _n), c0 in zip(pitems, cursors_before):
             got = job.cursor - c0
             if got:
                 landed += got
+                if not job.chunks:
+                    job.t_start = t_step
                 job.chunks += 1
                 job.t_progress = time.time()
                 self.prefill_chunks.append((job.sid, self.iterations, got))
@@ -524,22 +551,27 @@ class ContinuousDecodeLoop(threading.Thread):
     def _run_loop(self):
         while True:
             self.last_pass = time.time()
+            acc = {}                         # this pass's phase seconds
             with self.cv:
                 if not self.running:
                     break
-                expired = self._admit_locked()
-                victims = self._plan_preempt_locked()
-                if not self.active and not expired and not victims and \
-                        not self.prefill_waiting:
+                with phase(acc, "llm.loop.plan"):
+                    expired = self._admit_locked()
+                    victims = self._plan_preempt_locked()
+                    idle = not self.active and not expired and \
+                        not victims and not self.prefill_waiting
+                    if not idle:
+                        batch = list(self.active)
+                        self.max_resident = max(self.max_resident,
+                                                len(batch))
+                        dcost = self._decode_cost(batch)
+                        pitems = self._take_prefill_locked(dcost)
+                        self._inflight_prefill = frozenset(
+                            id(j) for j, _ in pitems)
+                        pwaiting = bool(self.prefill_waiting)
+                if idle:
                     self.cv.wait(timeout=self.idle_wait)
                     continue
-                batch = list(self.active)
-                self.max_resident = max(self.max_resident, len(batch))
-                dcost = self._decode_cost(batch)
-                pitems = self._take_prefill_locked(dcost)
-                self._inflight_prefill = frozenset(
-                    id(j) for j, _ in pitems)
-                pwaiting = bool(self.prefill_waiting)
             for seq in expired:
                 self._evict(seq, error=TimeoutError(
                     f"decode {seq.sid} not admitted within "
@@ -589,6 +621,7 @@ class ContinuousDecodeLoop(threading.Thread):
             # token, preserving the legacy step-per-iteration behavior
             before = [len(seq.tokens) for seq in batch]
             pbefore = [job.cursor for job, _ in pitems]
+            t_step = time.time()
             try:
                 if pitems:
                     # mixed pass: all resident decode tokens first, then
@@ -611,63 +644,83 @@ class ContinuousDecodeLoop(threading.Thread):
                     self._evict_prefill(job, error=e)
                 continue
             self.iterations += 1
-            landed = self._note_prefill_progress(pitems, pbefore)
-            if pitems:
-                with self.cv:
-                    self._inflight_prefill = frozenset()
-                    self.cv.notify_all()
-            if pitems:
-                self.mixed_log.append(
-                    (dcost, sum(n for _, n in pitems), landed))
-                if not landed:
-                    self._expire_prefill()
-                    if not batch:     # nothing advanced at all this pass
-                        time.sleep(self.idle_wait)
+            with phase(acc, "llm.loop.emit", batch=len(batch)):
+                landed = self._note_prefill_progress(pitems, pbefore,
+                                                     t_step)
+                if pitems:
+                    with self.cv:
+                        self._inflight_prefill = frozenset()
+                        self.cv.notify_all()
+                    self.mixed_log.append(
+                        (dcost, sum(n for _, n in pitems), landed))
+                    if not landed:
+                        self._expire_prefill()
+            if pitems and not landed and not batch:
+                time.sleep(self.idle_wait)   # nothing advanced this pass
             if landed:
                 # a prefill chunk landing changes pool block state
                 # mid-pass: re-check engine admission for deferred
                 # waiters NOW (try_admit is re-evaluated fresh — a
                 # pre-chunk admission decision must never be reused)
-                with self.cv:
+                with self.cv, phase(acc, "llm.loop.plan"):
                     late = self._admit_locked()
                 for seq in late:
                     self._evict(seq, error=TimeoutError(
                         f"decode {seq.sid} not admitted within "
                         f"{self.admit_timeout}s (KV pool backpressure)"))
-            finished, errored = [], []
-            pol = getattr(self.engine, "slo", None)
-            for seq, n_before in zip(batch, before):
-                seq.steps += max(1, len(seq.tokens) - n_before)
-                if pol is not None:
-                    # TTFT on the first pass, TBT per pass after that
-                    pol.note_tokens(seq)
-                # a failing per-sequence emission (on_text runs stream
-                # plumbing and the first-chunk early-release hook) fails
-                # THAT sequence, never the shared loop
-                try:
-                    if seq.on_text is not None:
-                        seq.on_text(seq.text_fn(seq))
-                except Exception as e:  # noqa: BLE001
-                    errored.append((seq, e))
-                    continue
-                if seq.steps >= seq.n:
-                    finished.append(seq)
-            if finished or errored:
-                with self.cv:
-                    for seq in finished:
-                        self.active.remove(seq)
-                    for seq, _ in errored:
-                        self.active.remove(seq)
-                for seq, e in errored:
-                    self._evict(seq, error=e)
-                for seq in finished:        # slot freed before next admit
-                    self._evict(seq)
+            with phase(acc, "llm.loop.emit", batch=len(batch)):
+                self._emit(batch, before)
+            self.engine.note_loop_phases(acc)
+
+    def _emit(self, batch, before):
+        """Per-sequence emission after a pass: count steps, hand each
+        sequence's text to ``on_text``, evict the finished ones."""
+        finished, errored = [], []
+        pol = getattr(self.engine, "slo", None)
+        for seq, n_before in zip(batch, before):
+            seq.steps += max(1, len(seq.tokens) - n_before)
+            if pol is not None:
+                # TTFT on the first pass, TBT per pass after that
+                pol.note_tokens(seq)
+            # a failing per-sequence emission (on_text runs stream
+            # plumbing and the first-chunk early-release hook) fails
+            # THAT sequence, never the shared loop
+            try:
+                if seq.on_text is not None:
+                    seq.on_text(seq.text_fn(seq))
+            except Exception as e:  # noqa: BLE001
+                errored.append((seq, e))
+                continue
+            if seq.steps >= seq.n:
+                finished.append(seq)
+        if finished or errored:
+            with self.cv:
+                for seq in finished:
+                    self.active.remove(seq)
+                for seq, _ in errored:
+                    self.active.remove(seq)
+            for seq, e in errored:
+                self._evict(seq, error=e)
+            for seq in finished:        # slot freed before next admit
+                self._evict(seq)
 
 
 class DecodeLoopMixin:
     """Decode-loop lifecycle shared by the real and sim LLM engines.
-    Host class must provide ``_lock``, ``max_batch`` and initialize
-    ``_decode_loop = None``."""
+    Host class must provide ``_lock``, ``max_batch``, a ``stats`` dict
+    holding ``plan_s`` and ``emit_s`` with its ``_stats_lock``, and
+    initialize ``_decode_loop = None``."""
+
+    def counters(self) -> dict:
+        """A copy of ``stats``, taken under its lock."""
+        with self._stats_lock:
+            return dict(self.stats)
+
+    def note_loop_phases(self, acc: dict):
+        """Add one loop pass's phase seconds (``plan_s``, ``emit_s``)."""
+        with self._stats_lock:
+            for k, v in acc.items():
+                self.stats[k] += v
 
     def start_decode_loop(self) -> ContinuousDecodeLoop:
         """Start (or return) this replica's persistent decode loop. An

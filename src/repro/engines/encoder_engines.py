@@ -1,7 +1,6 @@
 """Embedding and reranking engines wrapping the JAX encoder models."""
 from __future__ import annotations
 
-import time
 from typing import List
 
 import jax
@@ -31,7 +30,7 @@ class _EncoderEngine:
         self.tok = HashTokenizer(cfg.vocab_size)
         self.params = init_encoder_params(cfg, jax.random.key(seed))
         self._fwd = jax.jit(lambda p, t, m: apply_encoder(cfg, p, t, m))
-        self.stats = {"requests": 0, "calls": 0, "busy_s": 0.0}
+        self.stats = {"requests": 0, "calls": 0}
 
     def clone(self, idx: int = 1):
         """Pool replica: shared weights/tokenizer/jitted forward, fresh
@@ -43,11 +42,10 @@ class _EncoderEngine:
         c.tok = self.tok
         c.params = self.params
         c._fwd = self._fwd
-        c.stats = {"requests": 0, "calls": 0, "busy_s": 0.0}
+        c.stats = {"requests": 0, "calls": 0}
         return c
 
     def _encode_batch(self, texts: List[str]):
-        t0 = time.time()
         B = _bucket(len(texts), _BUCKETS_B)
         S = _bucket(max(1, max(len(t.split()) for t in texts)), _BUCKETS_S)
         toks = np.zeros((B, S), np.int32)
@@ -60,7 +58,6 @@ class _EncoderEngine:
                                    jnp.asarray(mask)))
         self.stats["requests"] += len(texts)
         self.stats["calls"] += 1
-        self.stats["busy_s"] += time.time() - t0
         return out[:len(texts)]
 
 

@@ -42,7 +42,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.engines.decode_loop import (ContinuousDecodeLoop, DecodeLoopMixin,
-                                       DecodeSeq, PrefillJob)
+                                       DecodeSeq, PrefillJob, phase)
 from repro.engines.tokenizer import HashTokenizer
 from repro.models.transformer import apply_model, init_params
 from repro.serving import kv_cache as kvc
@@ -56,6 +56,22 @@ def _bucket(n, buckets):
         if n <= b:
             return b
     return buckets[-1]
+
+
+def _new_stats() -> dict:
+    """An engine's counters. Prompts: ``prompt_tokens`` (every prefill
+    task's prompt, before any fork) = ``radix_hit_tokens`` (forked from
+    the radix cache, or without it from a warmed instruction's state) +
+    the tokens it prefilled + those the ``max_len`` clamp cut.
+    ``new_shapes``: step calls on a (jitted step, argument shapes) pair
+    this engine had not run. ``<phase>_s``: host seconds of each
+    continuous-loop phase (``decode_loop.phase``)."""
+    return {"prefill_tokens": 0, "decode_tokens": 0, "calls": 0,
+            "decode_iters": 0, "migrations_in": 0, "migrated_blocks": 0,
+            "migrate_s": 0.0, "prompt_tokens": 0, "radix_hit_tokens": 0,
+            "new_shapes": 0, "plan_s": 0.0, "prepare_s": 0.0,
+            "launch_s": 0.0, "wait_s": 0.0, "readback_s": 0.0,
+            "emit_s": 0.0}
 
 
 @dataclass
@@ -174,10 +190,8 @@ class LLMEngine(DecodeLoopMixin):
             self.radix = None
             self.meter = kvc.OccupancyMeter(kvc.bytes_per_token(cfg),
                                             decode_slots=max_batch)
-        self.stats = {"prefill_tokens": 0, "decode_tokens": 0, "calls": 0,
-                      "decode_iters": 0, "busy_s": 0.0,
-                      "migrations_in": 0, "migrated_blocks": 0,
-                      "migrate_s": 0.0}
+        self.stats = _new_stats()
+        self._shapes = set()              # (step name, argument shapes) run
         # decode_iteration (loop thread) and prefill/decode batches
         # (scheduler thread) update stats concurrently
         self._stats_lock = threading.Lock()
@@ -258,10 +272,8 @@ class LLMEngine(DecodeLoopMixin):
             c.radix = None
             c.meter = kvc.OccupancyMeter(self.meter.bytes_per_tok,
                                          decode_slots=c.max_batch)
-        c.stats = {"prefill_tokens": 0, "decode_tokens": 0, "calls": 0,
-                   "decode_iters": 0, "busy_s": 0.0,
-                   "migrations_in": 0, "migrated_blocks": 0,
-                   "migrate_s": 0.0}
+        c.stats = _new_stats()
+        c._shapes = set()
         c._stats_lock = threading.Lock()
         c._decode_loop = None            # per-replica decode loop
         c._pads = []
@@ -278,6 +290,16 @@ class LLMEngine(DecodeLoopMixin):
         """Commit an array, a host buffer or a pytree to this replica's
         device (no copy when it is already there)."""
         return jax.device_put(x, self.device)
+
+    def _note_shape(self, step, *args):
+        """Count a call of the jitted ``step`` on argument shapes this
+        engine has not run before (``stats["new_shapes"]``)."""
+        key = (step.__name__,) + tuple(a.shape for a in args)
+        if key not in self._shapes:
+            with self._stats_lock:
+                if key not in self._shapes:
+                    self._shapes.add(key)
+                    self.stats["new_shapes"] += 1
 
     def _new_pool(self):
         with jax.default_device(self.device):
@@ -367,18 +389,18 @@ class LLMEngine(DecodeLoopMixin):
     def _build_step(self):
         cfg = self.cfg
 
-        def step(params, tokens, cache, pos):
+        def llm_decode_step(params, tokens, cache, pos):
             logits, cache, _ = apply_model(cfg, params, tokens, cache, pos,
                                            q_block=256, remat=False,
                                            logits_slice=1)
             return logits[:, -1], cache
 
-        return jax.jit(step)
+        return jax.jit(llm_decode_step)
 
     def _build_prefill_step(self):
         cfg = self.cfg
 
-        def step(params, tokens, cache, pos, last_idx):
+        def llm_prefill_step(params, tokens, cache, pos, last_idx):
             # exact bucketed prefill: per-sequence logits at chunk index
             # len(t)-1 (not the padded tail)
             logits, cache, _ = apply_model(cfg, params, tokens, cache, pos,
@@ -386,7 +408,7 @@ class LLMEngine(DecodeLoopMixin):
                                            logits_at=last_idx)
             return logits[:, 0], cache
 
-        return jax.jit(step)
+        return jax.jit(llm_prefill_step)
 
     # The pool argument is DONATED in both paged steps: the engine holds
     # the only reference (mutation is serialized by _paged_lock and
@@ -395,26 +417,27 @@ class LLMEngine(DecodeLoopMixin):
     def _build_paged_step(self):
         cfg = self.cfg
 
-        def step(params, tokens, pool, tables, pos):
+        def llm_paged_decode_step(params, tokens, pool, tables, pos):
             logits, pool, _ = apply_model(cfg, params, tokens, pool, pos,
                                           q_block=256, remat=False,
                                           logits_slice=1,
                                           block_tables=tables)
             return logits[:, -1], pool
 
-        return jax.jit(step, donate_argnums=(2,))
+        return jax.jit(llm_paged_decode_step, donate_argnums=(2,))
 
     def _build_paged_prefill_step(self):
         cfg = self.cfg
 
-        def step(params, tokens, pool, tables, pos, last_idx):
+        def llm_paged_prefill_step(params, tokens, pool, tables, pos,
+                                   last_idx):
             logits, pool, _ = apply_model(cfg, params, tokens, pool, pos,
                                           q_block=256, remat=False,
                                           logits_at=last_idx,
                                           block_tables=tables)
             return logits[:, 0], pool
 
-        return jax.jit(step, donate_argnums=(2,))
+        return jax.jit(llm_paged_prefill_step, donate_argnums=(2,))
 
     # -- speculative verification steps: write a (k+1)-token chunk and
     # return logits at EVERY chunk position (the causal position mask
@@ -423,25 +446,25 @@ class LLMEngine(DecodeLoopMixin):
     def _build_verify_step(self):
         cfg = self.cfg
 
-        def step(params, tokens, cache, pos):
+        def llm_verify_step(params, tokens, cache, pos):
             logits, cache, _ = apply_model(cfg, params, tokens, cache, pos,
                                            q_block=256, remat=False)
             return logits, cache
 
         # the stacked cache is freshly concatenated per call — donate it
         # so verification never holds two copies of the batch KV
-        return jax.jit(step, donate_argnums=(2,))
+        return jax.jit(llm_verify_step, donate_argnums=(2,))
 
     def _build_paged_verify_step(self):
         cfg = self.cfg
 
-        def step(params, tokens, pool, tables, pos):
+        def llm_paged_verify_step(params, tokens, pool, tables, pos):
             logits, pool, _ = apply_model(cfg, params, tokens, pool, pos,
                                           q_block=256, remat=False,
                                           block_tables=tables)
             return logits, pool
 
-        return jax.jit(step, donate_argnums=(2,))
+        return jax.jit(llm_paged_verify_step, donate_argnums=(2,))
 
     def spec_verify(self, chunk_items, loop_sids=None):
         """ONE multi-position target forward over drafted chunks.
@@ -479,6 +502,7 @@ class LLMEngine(DecodeLoopMixin):
                         if resv is not None:
                             self._decode_reserved[sid] = max(0, resv - got)
                 tables, pos = self._table_batch(states, B, S)
+                self._note_shape(self._paged_vstep, toks, tables)
                 logits, self.pool = self._paged_vstep(
                     self.params, self._put(toks), self.pool, tables, pos)
             finally:
@@ -488,6 +512,7 @@ class LLMEngine(DecodeLoopMixin):
             # are discarded), not fresh max_len caches per call
             pad_states = states + self._pad_states(B - len(states))
             cache, pos = self._stack_states(pad_states)
+            self._note_shape(self._vstep, toks)
             logits, cache = self._vstep(self.params, self._put(toks),
                                         cache, pos)
             self._unstack(cache, pad_states)
@@ -689,7 +714,6 @@ class LLMEngine(DecodeLoopMixin):
         which are EXACT: gathered at chunk index len(t)-1, so bucketed
         (right-padded) prefill matches unpadded prefill token-for-token."""
         self._fault("prefill")
-        t0 = time.time()
         B = _bucket(len(items), BUCKETS_B)
         S = _bucket(max(len(t) for _, t in items), BUCKETS_S)
         toks, last_idx = self._prefill_toks(items, B, S)
@@ -704,7 +728,6 @@ class LLMEngine(DecodeLoopMixin):
         with self._stats_lock:
             self.stats["prefill_tokens"] += sum(len(t) for _, t in items)
             self.stats["calls"] += 1
-            self.stats["busy_s"] += time.time() - t0
         return logits[:len(items)]
 
     def prefill_chunked(self, items, chunk: Optional[int] = None):
@@ -735,6 +758,7 @@ class LLMEngine(DecodeLoopMixin):
         pad_states = states + [self.new_state()
                                for _ in range(B - len(states))]
         cache, pos = self._stack_states(pad_states)
+        self._note_shape(self._pstep, toks)
         logits, cache = self._pstep(self.params, self._put(toks),
                                     cache, pos, self._put(last_idx))
         self._unstack(cache, pad_states)
@@ -762,6 +786,7 @@ class LLMEngine(DecodeLoopMixin):
         """Run the jitted paged prefill step (caller holds _paged_lock
         with the write range already prepared/COW-resolved)."""
         tables, pos = self._table_batch(states, B, S)
+        self._note_shape(self._paged_pstep, toks, tables)
         logits, self.pool = self._paged_pstep(
             self.params, self._put(toks), self.pool, tables, pos,
             self._put(last_idx))
@@ -781,7 +806,6 @@ class LLMEngine(DecodeLoopMixin):
         return self._decode_batch_base(items, on_chunk)
 
     def _decode_batch_base(self, items, on_chunk=None):
-        t0 = time.time()
         n_max = max(n for _, n in items)
         B = _bucket(len(items), BUCKETS_B)
         states = [s for s, _ in items]
@@ -795,6 +819,7 @@ class LLMEngine(DecodeLoopMixin):
                                      np.int32))
             outs = [[] for _ in pad_states]
             emitted = [0] * len(items)
+            self._note_shape(self._step, cur)
             for t in range(n_max):
                 logits, cache = self._step(self.params, cur, cache, pos)
                 nxt = jnp.argmax(logits, axis=-1)
@@ -818,7 +843,6 @@ class LLMEngine(DecodeLoopMixin):
         with self._stats_lock:
             self.stats["decode_tokens"] += sum(n for _, n in items)
             self.stats["calls"] += 1
-            self.stats["busy_s"] += time.time() - t0
         return results
 
     def _paged_decode_batch(self, items, B, n_max, on_chunk):
@@ -843,6 +867,7 @@ class LLMEngine(DecodeLoopMixin):
             cur = self._put(cur)
             outs = [[] for _ in range(B)]
             emitted = [0] * len(items)
+            self._note_shape(self._paged_step, cur, tables)
             for t in range(n_max):
                 logits, self.pool = self._paged_step(
                     self.params, cur, self.pool, tables, pos)
@@ -1008,55 +1033,71 @@ class LLMEngine(DecodeLoopMixin):
         reservations stay untouchable) and when the pool — or its lock,
         held by a scheduler-side batch — is busy, the chunk is DECLINED:
         the job stays queued and the loop retries next pass. The decode
-        loop must never sleep on prefill backpressure."""
+        loop must never sleep on prefill backpressure. Timed in the
+        loop's step phases (``decode_loop.phase``)."""
         self._fault("prefill")
-        t0 = time.time()
+        acc = {}
+        tag = {"kind": "prefill", "batch": len(pitems)}
         items = []                       # (job, chunk_token_list)
         if self.paged:
             if not self._paged_lock.acquire(blocking=False):
                 return
             try:
-                free = self.alloc.free_blocks() - self._reserved_locked()
-                for job, n in pitems:
-                    chunk = job.tokens[job.cursor:job.cursor + n]
-                    need = self._blocks_needed(job.state, len(chunk))
-                    if need > free and self.radix is not None:
-                        # reclaim cached leaves (non-blocking, decrefs
-                        # only) before declining the chunk
-                        free += self.radix.evict(need - free)
-                    if need <= free:
-                        free -= need
-                        items.append((job, chunk))
-                if not items:
-                    return
-                for job, chunk in items:
-                    self._prepare_write(job.state, len(chunk))
+                with phase(acc, "llm.step.prepare", **tag):
+                    free = self.alloc.free_blocks() - \
+                        self._reserved_locked()
+                    for job, n in pitems:
+                        chunk = job.tokens[job.cursor:job.cursor + n]
+                        need = self._blocks_needed(job.state, len(chunk))
+                        if need > free and self.radix is not None:
+                            # reclaim cached leaves (non-blocking,
+                            # decrefs only) before declining the chunk
+                            free += self.radix.evict(need - free)
+                        if need <= free:
+                            free -= need
+                            items.append((job, chunk))
+                    if not items:
+                        return
+                    for job, chunk in items:
+                        self._prepare_write(job.state, len(chunk))
+                    B = _bucket(len(items), BUCKETS_B)
+                    S = _bucket(max(len(c) for _, c in items), BUCKETS_S)
+                    toks, last_idx = self._prefill_toks(
+                        [(j.state, c) for j, c in items], B, S)
+                    tables, pos = self._table_batch(
+                        [j.state for j, _ in items], B, S)
+                    self._note_shape(self._paged_pstep, toks, tables)
+                    toks, last_idx = self._put(toks), self._put(last_idx)
+                with phase(acc, "llm.step.launch", **tag):
+                    logits, self.pool = self._paged_pstep(
+                        self.params, toks, self.pool, tables, pos, last_idx)
+            finally:
+                self._paged_lock.release()
+        else:
+            with phase(acc, "llm.step.prepare", **tag):
+                items = [(job, job.tokens[job.cursor:job.cursor + n])
+                         for job, n in pitems]
                 B = _bucket(len(items), BUCKETS_B)
                 S = _bucket(max(len(c) for _, c in items), BUCKETS_S)
                 toks, last_idx = self._prefill_toks(
                     [(j.state, c) for j, c in items], B, S)
-                logits = self._paged_prefill_exec(
-                    [j.state for j, _ in items], B, S, toks, last_idx)
-            finally:
-                self._paged_lock.release()
-        else:
-            items = [(job, job.tokens[job.cursor:job.cursor + n])
-                     for job, n in pitems]
-            B = _bucket(len(items), BUCKETS_B)
-            S = _bucket(max(len(c) for _, c in items), BUCKETS_S)
-            toks, last_idx = self._prefill_toks(
-                [(j.state, c) for j, c in items], B, S)
-            logits = self._dense_prefill_exec(
-                [j.state for j, _ in items], B, toks, last_idx)
-        for i, (job, chunk) in enumerate(items):
-            job.state.pos += len(chunk)
-            job.state.last_token = int(jnp.argmax(logits[i]))
-            job.cursor += len(chunk)
-            self.meter.advance(job.sid, len(chunk))
+            with phase(acc, "llm.step.launch", **tag):
+                logits = self._dense_prefill_exec(
+                    [j.state for j, _ in items], B, toks, last_idx)
+        with phase(acc, "llm.step.wait", **tag):
+            first = int(jnp.argmax(logits[0]))
+        with phase(acc, "llm.step.readback", **tag):
+            for i, (job, chunk) in enumerate(items):
+                job.state.pos += len(chunk)
+                job.state.last_token = first if i == 0 else \
+                    int(jnp.argmax(logits[i]))
+                job.cursor += len(chunk)
+                self.meter.advance(job.sid, len(chunk))
         with self._stats_lock:
             self.stats["prefill_tokens"] += sum(len(c) for _, c in items)
             self.stats["calls"] += 1
-            self.stats["busy_s"] += time.time() - t0
+            for k, v in acc.items():
+                self.stats[k] += v
 
     def try_admit(self, seq: DecodeSeq) -> bool:
         """Block-level admission control (decode-loop hook): admit only
@@ -1191,7 +1232,6 @@ class LLMEngine(DecodeLoopMixin):
         """Prefill ``toks`` for a resuming sequence, bucketed-chunk by
         chunk. Paged mode bypasses free-block admission: the blocks come
         out of the sequence's own decode reservation."""
-        t0 = time.time()
         i = 0
         while i < len(toks):
             chunk = toks[i:i + BUCKETS_S[-1]]
@@ -1217,7 +1257,6 @@ class LLMEngine(DecodeLoopMixin):
         with self._stats_lock:
             self.stats["prefill_tokens"] += len(toks)
             self.stats["calls"] += 1
-            self.stats["busy_s"] += time.time() - t0
 
     def tenant_stats(self) -> dict:
         """Per-(tenant, class) scheduling stats (empty when SLO
@@ -1272,49 +1311,67 @@ class LLMEngine(DecodeLoopMixin):
         batch cache at all — every iteration scatters one token per
         sequence into the shared pool through a block table rebuilt from
         host-side lists (B*maxblk int32s, trivial next to the KV pytree
-        restack the dense path pays on every admission/eviction)."""
-        t0 = time.time()
+        restack the dense path pays on every admission/eviction).
+
+        Timed in the loop's step phases (``decode_loop.phase``)."""
+        acc = {}
+        tag = {"kind": "decode", "batch": len(seqs)}
         B = _bucket(len(seqs), BUCKETS_B)
         if self.paged:
-            with self._paged_lock:
-                for r in seqs:
-                    got = self._prepare_write(r.state, 1)
-                    if got:
-                        resv = self._decode_reserved.get(r.sid)
-                        if resv is not None:
-                            self._decode_reserved[r.sid] = max(0,
-                                                               resv - got)
-                states = [r.state for r in seqs]
-                tables, pos = self._table_batch(states, B, 1)
-                cur = np.ones((B, 1), np.int32)
-                cur[:len(states), 0] = [s.last_token for s in states]
-                logits, self.pool = self._paged_step(
-                    self.params, self._put(cur), self.pool, tables, pos)
+            try:
+                with phase(acc, "llm.step.prepare", **tag):
+                    self._paged_lock.acquire()
+                    for r in seqs:
+                        got = self._prepare_write(r.state, 1)
+                        if got:
+                            resv = self._decode_reserved.get(r.sid)
+                            if resv is not None:
+                                self._decode_reserved[r.sid] = \
+                                    max(0, resv - got)
+                    states = [r.state for r in seqs]
+                    tables, pos = self._table_batch(states, B, 1)
+                    cur = np.ones((B, 1), np.int32)
+                    cur[:len(states), 0] = [s.last_token for s in states]
+                    self._note_shape(self._paged_step, cur, tables)
+                    cur = self._put(cur)
+                with phase(acc, "llm.step.launch", **tag):
+                    logits, self.pool = self._paged_step(
+                        self.params, cur, self.pool, tables, pos)
+            finally:
+                self._paged_lock.release()
         else:
-            key = tuple(id(r) for r in seqs)
-            if key != self._batch_key:
-                self._flush_batch_cache()
-                self._batch_states = [r.state for r in seqs] + \
-                    self._pad_states(B - len(seqs))
-                self._batch_cache, self._batch_pos = \
-                    self._stack_states(self._batch_states)
-                self._batch_key = key
-            cur = self._put(np.array(
-                [[s.last_token] for s in self._batch_states], np.int32))
-            logits, self._batch_cache = self._step(
-                self.params, cur, self._batch_cache, self._batch_pos)
-            self._batch_pos = self._batch_pos + 1
-        nxt = jnp.argmax(logits, axis=-1)
-        for i, r in enumerate(seqs):
-            tok = int(nxt[i])
-            r.state.pos += 1
-            r.state.last_token = tok
-            r.tokens.append(tok)
-            self.meter.advance(r.sid, 1)
+            with phase(acc, "llm.step.prepare", **tag):
+                key = tuple(id(r) for r in seqs)
+                if key != self._batch_key:
+                    self._flush_batch_cache()
+                    self._batch_states = [r.state for r in seqs] + \
+                        self._pad_states(B - len(seqs))
+                    self._batch_cache, self._batch_pos = \
+                        self._stack_states(self._batch_states)
+                    self._batch_key = key
+                cur = np.array([[s.last_token] for s in self._batch_states],
+                               np.int32)
+                self._note_shape(self._step, cur)
+                cur = self._put(cur)
+            with phase(acc, "llm.step.launch", **tag):
+                logits, self._batch_cache = self._step(
+                    self.params, cur, self._batch_cache, self._batch_pos)
+                self._batch_pos = self._batch_pos + 1
+        with phase(acc, "llm.step.wait", **tag):
+            nxt = jnp.argmax(logits, axis=-1)
+            first = int(nxt[0])
+        with phase(acc, "llm.step.readback", **tag):
+            for i, r in enumerate(seqs):
+                tok = first if i == 0 else int(nxt[i])
+                r.state.pos += 1
+                r.state.last_token = tok
+                r.tokens.append(tok)
+                self.meter.advance(r.sid, 1)
         with self._stats_lock:
             self.stats["decode_tokens"] += len(seqs)
             self.stats["decode_iters"] += 1
-            self.stats["busy_s"] += time.time() - t0
+            for k, v in acc.items():
+                self.stats[k] += v
         return logits[:len(seqs)]
 
     # -- high-level ops used by the schedulers ------------------------------
@@ -1375,6 +1432,7 @@ class LLMEngine(DecodeLoopMixin):
         forked prefix already covers the whole prompt."""
         sid = t["sid"]
         toks = self.tok.encode(t["text"])
+        n_prompt = len(toks)
         forked = False
         ptoks = []
         with self._lock:
@@ -1399,6 +1457,9 @@ class LLMEngine(DecodeLoopMixin):
                         else self.new_state()
                     forked = ps is not None
                 self.states[sid] = st
+        with self._stats_lock:
+            self.stats["prompt_tokens"] += n_prompt
+            self.stats["radix_hit_tokens"] += n_prompt - len(toks)
         toks = toks[: self.max_len - st.pos - 8]
         if forked and not toks:
             # prompt == cached instruction: the forked state is already

@@ -138,7 +138,8 @@ class SimLLMEngine(DecodeLoopMixin):
         self.stats = {"prefill_tokens": 0, "decode_tokens": 0, "calls": 0,
                       "decode_iters": 0, "busy_ms": 0.0,
                       "radix_hit_tokens": 0,
-                      "migrations_in": 0, "migrated_blocks": 0}
+                      "migrations_in": 0, "migrated_blocks": 0,
+                      "plan_s": 0.0, "emit_s": 0.0}
         self._stats_lock = threading.Lock()
         self._decode_loop = None
         # fault tolerance: injector hook + replica health (see LLMEngine)

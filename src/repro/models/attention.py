@@ -146,8 +146,9 @@ def paged_write(pool, chunk, block_tables, positions):
     collide (padding rows all target the reserved pad block — last write
     wins on scratch data)."""
     bs = pool.shape[1]
-    bid = jnp.take_along_axis(block_tables, positions // bs, axis=1)
-    return pool.at[bid, positions % bs].set(chunk.astype(pool.dtype))
+    with jax.named_scope("kv_write"):
+        bid = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+        return pool.at[bid, positions % bs].set(chunk.astype(pool.dtype))
 
 
 def paged_gather(pool, block_tables):

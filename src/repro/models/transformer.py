@@ -135,14 +135,13 @@ def apply_layer(cfg, spec, p, x, ce, pos, q_block, block_tables=None):
     if ce is not None:
         attn_cache_in = {k: v for k, v in ce.items()
                          if k in ("k", "v", "ckv", "krope")}
-    if cfg.attention_kind == "mla":
-        a_out, a_cache = attn.mla_layer(cfg, spec, p["attn"], h,
-                                        attn_cache_in, pos, q_block,
-                                        block_tables)
-    else:
-        a_out, a_cache = attn.gqa_layer(cfg, spec, p["attn"], h,
-                                        attn_cache_in, pos, q_block,
-                                        block_tables)
+    # named scopes label the device trace's operations by model part
+    # (the cache writes inside carry "kv_write")
+    layer = attn.mla_layer if cfg.attention_kind == "mla" \
+        else attn.gqa_layer
+    with jax.named_scope("attention"):
+        a_out, a_cache = layer(cfg, spec, p["attn"], h, attn_cache_in, pos,
+                               q_block, block_tables)
     nc = dict(a_cache) if a_cache is not None else None
 
     if spec.kind == "hybrid":
@@ -165,10 +164,11 @@ def apply_layer(cfg, spec, p, x, ce, pos, q_block, block_tables=None):
 
     # --- FFN ---
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if spec.moe:
-        f_out, aux = moe_mod.moe_ffn(cfg, p["moe"], h)
-    else:
-        f_out = _ffn(cfg, p["mlp"], h)
+    with jax.named_scope("mlp"):
+        if spec.moe:
+            f_out, aux = moe_mod.moe_ffn(cfg, p["moe"], h)
+        else:
+            f_out = _ffn(cfg, p["mlp"], h)
     x = x + f_out
     return x, nc, aux
 

@@ -892,7 +892,8 @@ def write_linear(buf, chunk, pos):
         start = (p,) + (0,) * (b.ndim - 1)
         return jax.lax.dynamic_update_slice(b, c.astype(b.dtype), start)
 
-    return jax.vmap(one)(buf, chunk, pos)
+    with jax.named_scope("kv_write"):
+        return jax.vmap(one)(buf, chunk, pos)
 
 
 def write_ring(buf, chunk, pos):
@@ -906,7 +907,8 @@ def write_ring(buf, chunk, pos):
     def one(b, c, ix):
         return b.at[ix].set(c.astype(b.dtype))
 
-    return jax.vmap(one)(buf, chunk, idx)
+    with jax.named_scope("kv_write"):
+        return jax.vmap(one)(buf, chunk, idx)
 
 
 def slot_positions_linear(T, length):
