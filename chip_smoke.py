@@ -190,29 +190,50 @@ def engine_summary(eng):
 
 def logits_check(eng, prompt=LOGITS_PROMPT, steps=DECODE_STEPS):
     """Prefill ``prompt`` and take ``steps`` decode steps through the
-    engine's own paths, then compare every step's logits with
-    ``reference_logits`` over the same tokens. Returns
-    (ok, worst_rel_rms, worst_max_rel)."""
+    paged engine's own paths, then compare every step's logits with
+    ``reference_logits`` over the same tokens. The step programs return
+    only token ids, so each step's logits are read from the arguments the
+    engine passed it, through ``step_logits`` (the programs' own body).
+    Returns (ok, worst_rel_rms, worst_max_rel)."""
+    import jax
     import numpy as np
     from repro.engines.decode_loop import DecodeSeq
+    from repro.engines.llm_engine import step_logits
     from repro.models.transformer import reference_logits
+    cfg = eng.cfg
+    peek = jax.jit(lambda params, tokens, pool, tables, pos, last_idx=None:
+                   step_logits(cfg, params, tokens, pool, pos, tables,
+                               last_idx)[0])
+    got = []
+
+    def watched(real):
+        def step(*args):
+            got.append(np.asarray(peek(*args)[0], np.float32))
+            return real(*args)
+        step.__name__ = real.__name__
+        return step
+
+    steps_of = {k: getattr(eng, k) for k in ("_paged_pstep", "_paged_step")}
+    for k, real in steps_of.items():
+        setattr(eng, k, watched(real))
     toks = eng.tok.encode(prompt)
     sid = "smoke-logits"
     st = eng.new_state()
     eng.states[sid] = st
     try:
-        got = [np.asarray(eng.prefill_batch([(st, toks)])[0], np.float32)]
+        eng.prefill_batch([(st, toks)])
         fed = [st.last_token]
         seq = DecodeSeq(sid, st, steps, text_fn=eng.tok.decode)
         for _ in range(steps):
-            got.append(np.asarray(eng.decode_iteration([seq])[0],
-                                  np.float32))
+            eng.decode_iteration([seq])
             fed.append(st.last_token)
         tokens = toks + fed[:steps]
         ref = reference_logits(eng.cfg, eng.params,
                                eng._put(np.asarray([tokens], np.int32)))
         ref = np.asarray(ref[0, len(toks) - 1:], np.float32)
     finally:
+        for k, real in steps_of.items():
+            setattr(eng, k, real)
         eng.release(sid)
     worst_rms = worst_max = 0.0
     for i, (g, r) in enumerate(zip(got, ref)):
@@ -224,7 +245,8 @@ def logits_check(eng, prompt=LOGITS_PROMPT, steps=DECODE_STEPS):
         log(f"logits {'prefill' if i == 0 else f'decode {i}'} "
             f"(position {len(toks) - 1 + i}): rel_rms_err={rel_rms:.5f} "
             f"max_rel_err={max_rel:.5f} ref_rms={scale:.4f} "
-            f"argmax engine/ref {int(g.argmax())}/{int(r.argmax())}")
+            f"token engine/logits/ref {fed[i]}/{int(g.argmax())}/"
+            f"{int(r.argmax())}")
     ok = worst_rms <= REL_RMS_TOL and worst_max <= MAX_REL_TOL
     log(f"logits check {'PASS' if ok else 'FAIL'}: {len(got)} positions, "
         f"worst rel_rms_err {worst_rms:.5f} (tol {REL_RMS_TOL}), worst "

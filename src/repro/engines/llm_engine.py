@@ -58,20 +58,40 @@ def _bucket(n, buckets):
     return buckets[-1]
 
 
+def step_logits(cfg, params, tokens, cache, pos, tables=None,
+                last_idx=None):
+    """One serving step's next-token logits (B, vocab) and the cache (the
+    paged pool, with block ``tables``) holding the step's KV. The logits
+    sit at each row's last position, or at chunk index ``last_idx`` (B,):
+    exact bucketed prefill, not the padded tail."""
+    at = {"logits_slice": 1} if last_idx is None else {"logits_at": last_idx}
+    logits, cache, _ = apply_model(cfg, params, tokens, cache, pos,
+                                   q_block=256, remat=False,
+                                   block_tables=tables, **at)
+    return logits[:, 0], cache
+
+
+def greedy(logits):
+    """Greedy token ids over the last axis: the one pick every step
+    program makes, so only ids leave the device."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def _new_stats() -> dict:
     """An engine's counters. Prompts: ``prompt_tokens`` (every prefill
     task's prompt, before any fork) = ``radix_hit_tokens`` (forked from
     the radix cache, or without it from a warmed instruction's state) +
     the tokens it prefilled + those the ``max_len`` clamp cut.
     ``new_shapes``: step calls on a (jitted step, argument shapes) pair
-    this engine had not run. ``<phase>_s``: host seconds of each
+    this engine had not run. ``token_reads``: device-to-host reads of a
+    step's sampled ids, one per step call. ``<phase>_s``: host seconds of each
     continuous-loop phase (``decode_loop.phase``)."""
     return {"prefill_tokens": 0, "decode_tokens": 0, "calls": 0,
             "decode_iters": 0, "migrations_in": 0, "migrated_blocks": 0,
             "migrate_s": 0.0, "prompt_tokens": 0, "radix_hit_tokens": 0,
-            "new_shapes": 0, "plan_s": 0.0, "prepare_s": 0.0,
-            "launch_s": 0.0, "wait_s": 0.0, "readback_s": 0.0,
-            "emit_s": 0.0}
+            "new_shapes": 0, "token_reads": 0, "plan_s": 0.0,
+            "prepare_s": 0.0, "launch_s": 0.0, "wait_s": 0.0,
+            "readback_s": 0.0, "emit_s": 0.0}
 
 
 @dataclass
@@ -385,15 +405,15 @@ class LLMEngine(DecodeLoopMixin):
             return 0
         return self.radix.match_len(toks[:len(toks) - 1])
 
-    # -- jitted batched step: write chunk, return logits of last position
+    # -- jitted batched steps: write the chunk's KV and return the greedy
+    # next-token ids (B,), so one small transfer brings a step's tokens
+    # to the host and the logits never leave the device
     def _build_step(self):
         cfg = self.cfg
 
         def llm_decode_step(params, tokens, cache, pos):
-            logits, cache, _ = apply_model(cfg, params, tokens, cache, pos,
-                                           q_block=256, remat=False,
-                                           logits_slice=1)
-            return logits[:, -1], cache
+            logits, cache = step_logits(cfg, params, tokens, cache, pos)
+            return greedy(logits), cache
 
         return jax.jit(llm_decode_step)
 
@@ -401,12 +421,9 @@ class LLMEngine(DecodeLoopMixin):
         cfg = self.cfg
 
         def llm_prefill_step(params, tokens, cache, pos, last_idx):
-            # exact bucketed prefill: per-sequence logits at chunk index
-            # len(t)-1 (not the padded tail)
-            logits, cache, _ = apply_model(cfg, params, tokens, cache, pos,
-                                           q_block=256, remat=False,
-                                           logits_at=last_idx)
-            return logits[:, 0], cache
+            logits, cache = step_logits(cfg, params, tokens, cache, pos,
+                                        last_idx=last_idx)
+            return greedy(logits), cache
 
         return jax.jit(llm_prefill_step)
 
@@ -418,11 +435,9 @@ class LLMEngine(DecodeLoopMixin):
         cfg = self.cfg
 
         def llm_paged_decode_step(params, tokens, pool, tables, pos):
-            logits, pool, _ = apply_model(cfg, params, tokens, pool, pos,
-                                          q_block=256, remat=False,
-                                          logits_slice=1,
-                                          block_tables=tables)
-            return logits[:, -1], pool
+            logits, pool = step_logits(cfg, params, tokens, pool, pos,
+                                       tables)
+            return greedy(logits), pool
 
         return jax.jit(llm_paged_decode_step, donate_argnums=(2,))
 
@@ -431,25 +446,24 @@ class LLMEngine(DecodeLoopMixin):
 
         def llm_paged_prefill_step(params, tokens, pool, tables, pos,
                                    last_idx):
-            logits, pool, _ = apply_model(cfg, params, tokens, pool, pos,
-                                          q_block=256, remat=False,
-                                          logits_at=last_idx,
-                                          block_tables=tables)
-            return logits[:, 0], pool
+            logits, pool = step_logits(cfg, params, tokens, pool, pos,
+                                       tables, last_idx)
+            return greedy(logits), pool
 
         return jax.jit(llm_paged_prefill_step, donate_argnums=(2,))
 
     # -- speculative verification steps: write a (k+1)-token chunk and
-    # return logits at EVERY chunk position (the causal position mask
-    # keeps draft token i blind to drafts > i, so one forward scores the
-    # whole chunk — q_len generalizes the decode step's q_len=1).
+    # return the greedy ids (B, k+1) at EVERY chunk position (the causal
+    # position mask keeps draft token i blind to drafts > i, so one
+    # forward scores the whole chunk — q_len generalizes the decode
+    # step's q_len=1).
     def _build_verify_step(self):
         cfg = self.cfg
 
         def llm_verify_step(params, tokens, cache, pos):
             logits, cache, _ = apply_model(cfg, params, tokens, cache, pos,
                                            q_block=256, remat=False)
-            return logits, cache
+            return greedy(logits), cache
 
         # the stacked cache is freshly concatenated per call — donate it
         # so verification never holds two copies of the batch KV
@@ -462,9 +476,17 @@ class LLMEngine(DecodeLoopMixin):
             logits, pool, _ = apply_model(cfg, params, tokens, pool, pos,
                                           q_block=256, remat=False,
                                           block_tables=tables)
-            return logits, pool
+            return greedy(logits), pool
 
         return jax.jit(llm_paged_verify_step, donate_argnums=(2,))
+
+    def _read_ids(self, ids) -> np.ndarray:
+        """A step's sampled ids on the host, in ONE device-to-host
+        transfer (counted in ``token_reads``)."""
+        host = np.asarray(ids)
+        with self._stats_lock:
+            self.stats["token_reads"] += 1
+        return host
 
     def spec_verify(self, chunk_items, loop_sids=None):
         """ONE multi-position target forward over drafted chunks.
@@ -503,7 +525,7 @@ class LLMEngine(DecodeLoopMixin):
                             self._decode_reserved[sid] = max(0, resv - got)
                 tables, pos = self._table_batch(states, B, S)
                 self._note_shape(self._paged_vstep, toks, tables)
-                logits, self.pool = self._paged_vstep(
+                ids, self.pool = self._paged_vstep(
                     self.params, self._put(toks), self.pool, tables, pos)
             finally:
                 self._paged_lock.release()
@@ -513,10 +535,10 @@ class LLMEngine(DecodeLoopMixin):
             pad_states = states + self._pad_states(B - len(states))
             cache, pos = self._stack_states(pad_states)
             self._note_shape(self._vstep, toks)
-            logits, cache = self._vstep(self.params, self._put(toks),
-                                        cache, pos)
+            ids, cache = self._vstep(self.params, self._put(toks), cache,
+                                     pos)
             self._unstack(cache, pad_states)
-        return np.asarray(jnp.argmax(logits, axis=-1))[:len(states)]
+        return self._read_ids(ids)[:len(states)]
 
     def spec_rollback(self, st, sid=None):
         """Roll back rejected draft tokens: ``st.pos`` already stands at
@@ -710,25 +732,27 @@ class LLMEngine(DecodeLoopMixin):
     def prefill_batch(self, items):
         """items: list of (state, token_list). Pads to a (B,S) bucket and
         runs one chunked-prefill step per sequence position offset.
-        Returns the per-sequence next-token logits (len(items), vocab),
-        which are EXACT: gathered at chunk index len(t)-1, so bucketed
-        (right-padded) prefill matches unpadded prefill token-for-token."""
+        Returns the per-sequence greedy next-token ids (a list of
+        len(items)), which are EXACT: picked at chunk index len(t)-1, so
+        bucketed (right-padded) prefill matches unpadded prefill
+        token-for-token."""
         self._fault("prefill")
         B = _bucket(len(items), BUCKETS_B)
         S = _bucket(max(len(t) for _, t in items), BUCKETS_S)
         toks, last_idx = self._prefill_toks(items, B, S)
         if self.paged:
-            logits = self._paged_prefill(items, B, S, toks, last_idx)
+            ids = self._paged_prefill(items, B, S, toks, last_idx)
         else:
-            logits = self._dense_prefill_exec([s for s, _ in items], B,
-                                              toks, last_idx)
-        for i, (s, t) in enumerate(items):
+            ids = self._dense_prefill_exec([s for s, _ in items], B, toks,
+                                           last_idx)
+        nxt = self._read_ids(ids)[:len(items)].tolist()
+        for (s, t), tok in zip(items, nxt):
             s.pos += len(t)
-            s.last_token = int(jnp.argmax(logits[i]))
+            s.last_token = tok
         with self._stats_lock:
             self.stats["prefill_tokens"] += sum(len(t) for _, t in items)
             self.stats["calls"] += 1
-        return logits[:len(items)]
+        return nxt
 
     def prefill_chunked(self, items, chunk: Optional[int] = None):
         """Resumable chunked prefill: advance every item's prompt by at
@@ -759,10 +783,10 @@ class LLMEngine(DecodeLoopMixin):
                                for _ in range(B - len(states))]
         cache, pos = self._stack_states(pad_states)
         self._note_shape(self._pstep, toks)
-        logits, cache = self._pstep(self.params, self._put(toks),
-                                    cache, pos, self._put(last_idx))
+        ids, cache = self._pstep(self.params, self._put(toks), cache, pos,
+                                 self._put(last_idx))
         self._unstack(cache, pad_states)
-        return logits
+        return ids
 
     def _paged_prefill(self, items, B, S, toks, last_idx):
         """Paged prefill: allocate/COW only the blocks the REAL tokens
@@ -777,20 +801,20 @@ class LLMEngine(DecodeLoopMixin):
         try:
             for s, n in zip(states, lens):
                 self._prepare_write(s, n)
-            logits = self._paged_prefill_exec(states, B, S, toks, last_idx)
+            ids = self._paged_prefill_exec(states, B, S, toks, last_idx)
         finally:
             self._paged_lock.release()
-        return logits
+        return ids
 
     def _paged_prefill_exec(self, states, B, S, toks, last_idx):
         """Run the jitted paged prefill step (caller holds _paged_lock
         with the write range already prepared/COW-resolved)."""
         tables, pos = self._table_batch(states, B, S)
         self._note_shape(self._paged_pstep, toks, tables)
-        logits, self.pool = self._paged_pstep(
+        ids, self.pool = self._paged_pstep(
             self.params, self._put(toks), self.pool, tables, pos,
             self._put(last_idx))
-        return logits
+        return ids
 
     def decode_batch(self, items, on_chunk=None):
         """items: list of (state, n_tokens). Greedy continuous decode.
@@ -821,12 +845,11 @@ class LLMEngine(DecodeLoopMixin):
             emitted = [0] * len(items)
             self._note_shape(self._step, cur)
             for t in range(n_max):
-                logits, cache = self._step(self.params, cur, cache, pos)
-                nxt = jnp.argmax(logits, axis=-1)
-                for i in range(len(pad_states)):
-                    outs[i].append(int(nxt[i]))
-                cur = nxt[:, None].astype(jnp.int32)
+                ids, cache = self._step(self.params, cur, cache, pos)
+                cur = ids[:, None]
                 pos = pos + 1
+                for out, tok in zip(outs, self._read_ids(ids).tolist()):
+                    out.append(tok)
                 if on_chunk and ((t + 1) % self.stream_chunk == 0
                                  or t + 1 == n_max):
                     for i, (_, n) in enumerate(items):
@@ -869,13 +892,12 @@ class LLMEngine(DecodeLoopMixin):
             emitted = [0] * len(items)
             self._note_shape(self._paged_step, cur, tables)
             for t in range(n_max):
-                logits, self.pool = self._paged_step(
+                ids, self.pool = self._paged_step(
                     self.params, cur, self.pool, tables, pos)
-                nxt = jnp.argmax(logits, axis=-1)
-                for i in range(B):
-                    outs[i].append(int(nxt[i]))
-                cur = nxt[:, None].astype(jnp.int32)
+                cur = ids[:, None]
                 pos = jnp.minimum(pos + 1, limit)
+                for out, tok in zip(outs, self._read_ids(ids).tolist()):
+                    out.append(tok)
                 if on_chunk and ((t + 1) % self.stream_chunk == 0
                                  or t + 1 == n_max):
                     for i, (_, n) in enumerate(items):
@@ -1069,7 +1091,7 @@ class LLMEngine(DecodeLoopMixin):
                     self._note_shape(self._paged_pstep, toks, tables)
                     toks, last_idx = self._put(toks), self._put(last_idx)
                 with phase(acc, "llm.step.launch", **tag):
-                    logits, self.pool = self._paged_pstep(
+                    ids, self.pool = self._paged_pstep(
                         self.params, toks, self.pool, tables, pos, last_idx)
             finally:
                 self._paged_lock.release()
@@ -1082,15 +1104,14 @@ class LLMEngine(DecodeLoopMixin):
                 toks, last_idx = self._prefill_toks(
                     [(j.state, c) for j, c in items], B, S)
             with phase(acc, "llm.step.launch", **tag):
-                logits = self._dense_prefill_exec(
+                ids = self._dense_prefill_exec(
                     [j.state for j, _ in items], B, toks, last_idx)
         with phase(acc, "llm.step.wait", **tag):
-            first = int(jnp.argmax(logits[0]))
+            nxt = self._read_ids(ids).tolist()
         with phase(acc, "llm.step.readback", **tag):
-            for i, (job, chunk) in enumerate(items):
+            for (job, chunk), tok in zip(items, nxt):
                 job.state.pos += len(chunk)
-                job.state.last_token = first if i == 0 else \
-                    int(jnp.argmax(logits[i]))
+                job.state.last_token = tok
                 job.cursor += len(chunk)
                 self.meter.advance(job.sid, len(chunk))
         with self._stats_lock:
@@ -1247,12 +1268,12 @@ class LLMEngine(DecodeLoopMixin):
                         if resv is not None:
                             self._decode_reserved[sid] = max(0,
                                                              resv - got)
-                    logits = self._paged_prefill_exec(
+                    ids = self._paged_prefill_exec(
                         [st], B, S, grid, last_idx)
             else:
-                logits = self._dense_prefill_exec([st], B, grid, last_idx)
+                ids = self._dense_prefill_exec([st], B, grid, last_idx)
             st.pos += len(chunk)
-            st.last_token = int(jnp.argmax(logits[0]))
+            st.last_token = int(self._read_ids(ids)[0])
             self.meter.advance(sid, len(chunk))
         with self._stats_lock:
             self.stats["prefill_tokens"] += len(toks)
@@ -1287,8 +1308,8 @@ class LLMEngine(DecodeLoopMixin):
         decoding enabled, sequences with enough remaining budget advance
         by a whole verified draft chunk per pass (the loop counts their
         emitted tokens); the rest — and everything, with it disabled —
-        take the single-token step, whose next-token logits
-        (len(seqs), vocab) are returned."""
+        take the single-token step. Each sequence's new token is appended
+        to its ``tokens``; nothing is returned."""
         self._fault("decode")
         if self.slo is not None:
             for r in seqs:
@@ -1335,7 +1356,7 @@ class LLMEngine(DecodeLoopMixin):
                     self._note_shape(self._paged_step, cur, tables)
                     cur = self._put(cur)
                 with phase(acc, "llm.step.launch", **tag):
-                    logits, self.pool = self._paged_step(
+                    ids, self.pool = self._paged_step(
                         self.params, cur, self.pool, tables, pos)
             finally:
                 self._paged_lock.release()
@@ -1354,15 +1375,13 @@ class LLMEngine(DecodeLoopMixin):
                 self._note_shape(self._step, cur)
                 cur = self._put(cur)
             with phase(acc, "llm.step.launch", **tag):
-                logits, self._batch_cache = self._step(
+                ids, self._batch_cache = self._step(
                     self.params, cur, self._batch_cache, self._batch_pos)
                 self._batch_pos = self._batch_pos + 1
         with phase(acc, "llm.step.wait", **tag):
-            nxt = jnp.argmax(logits, axis=-1)
-            first = int(nxt[0])
+            nxt = self._read_ids(ids).tolist()
         with phase(acc, "llm.step.readback", **tag):
-            for i, r in enumerate(seqs):
-                tok = first if i == 0 else int(nxt[i])
+            for r, tok in zip(seqs, nxt):
                 r.state.pos += 1
                 r.state.last_token = tok
                 r.tokens.append(tok)
@@ -1372,7 +1391,6 @@ class LLMEngine(DecodeLoopMixin):
             self.stats["decode_iters"] += 1
             for k, v in acc.items():
                 self.stats[k] += v
-        return logits[:len(seqs)]
 
     # -- high-level ops used by the schedulers ------------------------------
     def _match_prefix_locked(self, toks):

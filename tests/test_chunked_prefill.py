@@ -75,6 +75,52 @@ def test_loop_chunked_prefill_token_identity(paged):
     assert run(True) == run(False)
 
 
+# The KV cache holds bfloat16 keys and values, so the served float32
+# logits differ from the cache-free reference by up to about 2% of their
+# RMS on the tiny model; a near-tie within that may flip.
+TIE = 0.05          # of the reference logits' RMS at the position
+
+
+def test_loop_tokens_are_the_reference_greedy_picks():
+    """Tokens the continuous loop serves over the paged pool, with
+    chunked prefill and a radix-forked prompt, are the greedy argmax of
+    the cache-free float32 reference over the same weights: the step
+    programs pick on the device what the reference picks. Only at a
+    near-tie (two of these 26 positions) may the served token be
+    another, whose reference logit lies less than ``TIE`` below the
+    best."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.models.transformer import reference_logits
+    eng = LLMEngine("t", get_config("tiny-core-llm"), max_len=256,
+                    paged=True, chunked_prefill=True, block_size=8,
+                    prefill_chunk=16, prefix_cache="radix")
+    texts = {"a": LONG + " first question", "b": LONG + " second one"}
+    first = {}                           # the prefill's own pick
+    for sid, text in texts.items():        # b forks a's cached blocks
+        eng.op_prefill([{"sid": sid, "text": text}])
+        first[sid] = eng.states[sid].last_token
+    assert eng.counters()["radix_hit_tokens"] >= 64
+    seqs = {sid: eng.submit_decode(sid, 12) for sid in texts}
+    for seq in seqs.values():
+        seq.wait(120)
+    eng.stop_decode_loop()
+    gaps = []
+    for sid, text in texts.items():
+        prompt = eng.tok.encode(text)
+        served = [first[sid]] + list(seqs[sid].tokens)
+        assert len(served) == 13
+        ref = reference_logits(eng.cfg, eng.params, jnp.asarray(
+            [prompt + served[:-1]], jnp.int32))
+        ref = np.asarray(ref[0, len(prompt) - 1:])
+        rms = np.sqrt(np.mean(ref ** 2, axis=-1))
+        gap = (ref.max(axis=-1) - ref[np.arange(len(served)), served]) / rms
+        assert gap.max() <= TIE, (sid, gap.tolist())
+        gaps.extend(gap.tolist())
+    assert sum(g == 0.0 for g in gaps) >= len(gaps) - 2
+
+
 @pytest.mark.parametrize("paged", [False, True])
 def test_mixed_iterations_token_identity_with_resident_decodes(paged):
     """A long prompt arriving while decodes are resident advances in

@@ -10,7 +10,7 @@ import pytest
 from repro.configs.base import get_config
 from repro.core.apps import advanced_rag, build_engines
 from repro.core.teola import Teola
-from repro.engines.decode_loop import DecodeSeq, phase
+from repro.engines.decode_loop import DecodeSeq, PrefillJob, phase
 from repro.engines.llm_engine import LLMEngine
 from repro.engines.sim_engines import build_sim_engines
 from repro.training.data import doc_corpus
@@ -96,6 +96,45 @@ def test_new_shapes_counts_a_signature_once(paged):
     assert step(["b"]) == 0
     assert step(["a", "b"]) == 1         # batch bucket 2
     assert step(["b", "a"]) == 0
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_token_reads_count_step_calls_not_sequences(paged):
+    """Each step brings its sampled ids to the host in one read: loop
+    passes of ten resident decodes plus a prefill chunk, and a
+    run-to-completion batch, count one ``token_reads`` per step call."""
+    eng = _engine(paged=paged, max_batch=16)
+    n = 10
+    sids = [f"s{i}" for i in range(n)]
+    eng.op_prefill([{"sid": s, "text": f"question {s} about optics"}
+                    for s in sids])
+    calls = []                           # (step name, batch rows)
+    for k in ("_step", "_pstep", "_paged_step", "_paged_pstep"):
+        real = getattr(eng, k, None)
+        if real is None:
+            continue
+
+        def counted(*args, real=real):
+            calls.append((real.__name__, args[1].shape[0]))
+            return real(*args)
+        counted.__name__ = real.__name__
+        setattr(eng, k, counted)
+    seqs = [DecodeSeq(s, eng.states[s], 3, text_fn=lambda q: "")
+            for s in sids]
+    eng.states["p"] = eng.new_state()
+    job = PrefillJob("p", eng.states["p"], eng.tok.encode(SHARED))
+    before = eng.counters()
+    for _ in range(3):
+        eng.mixed_iteration(seqs, [(job, 8)])
+    mid = eng.counters()
+    assert mid["token_reads"] - before["token_reads"] == len(calls) == 6
+    assert mid["decode_tokens"] - before["decode_tokens"] == 3 * n
+    assert sorted(set(b for _, b in calls)) == [1, 16]
+    assert job.cursor == 24 and all(len(r.tokens) == 3 for r in seqs)
+    eng.op_decode([{"sid": s, "max_new": 4} for s in sids])
+    after = eng.counters()
+    assert after["token_reads"] - mid["token_reads"] == len(calls) - 6 == 4
+    assert after["decode_tokens"] - mid["decode_tokens"] == 4 * n
 
 
 def _query(teola):
