@@ -21,8 +21,9 @@ CHUNKED PREFILL (Sarathi-style stall-free mixed batches): with
 ``prefill_chunk > 0`` the loop also owns a PREFILL queue of
 ``PrefillJob``s — resumable per-sequence prompt cursors. Each pass packs
 all resident decode tokens FIRST, then fills the remaining per-pass
-``token_budget`` with prefill-chunk tokens, and hands both to the
-engine's ``mixed_iteration(seqs, prefill_items)``. A long prompt
+``token_budget`` with prefill-chunk tokens (at most the engine's
+``max_prefill_rows`` chunks), and hands both to the engine's
+``mixed_iteration(seqs, prefill_items)``. A long prompt
 therefore advances in bounded chunks BETWEEN decode steps instead of
 head-of-line-blocking every co-resident decode for a whole-prompt
 forward: decode time-between-tokens is bounded by one chunk's compute,
@@ -194,6 +195,9 @@ class ContinuousDecodeLoop(threading.Thread):
         self.token_budget = int(token_budget) if token_budget else \
             (self.prefill_chunk + self.max_slots if self.prefill_chunk
              else 0)
+        # set by each plan: whether the engine's cap on chunks per pass
+        # ended it with budget room left and a job still waiting
+        self.prefill_capped = False
         self.waiting: deque = deque()
         self.prefill_waiting: deque = deque()
         # ids of PrefillJobs whose chunk is inside the currently-executing
@@ -296,13 +300,17 @@ class ContinuousDecodeLoop(threading.Thread):
     def _take_prefill_locked(self, decode_cost: int):
         """Token-budget admission: plan prefill chunks for this pass —
         FIFO over the prefill queue, each job contributing at most one
-        chunk of min(prefill_chunk, remaining, budget room) tokens.
-        Decode tokens are packed first; prefill only ever takes the
-        leftover budget (decodes never wait behind a prompt)."""
+        chunk of min(prefill_chunk, remaining, budget room) tokens, and
+        at most the engine's ``max_prefill_rows`` chunks, where it has
+        such a cap (the rest keep their place for the next pass). Decode
+        tokens are packed first; prefill only ever takes the leftover
+        budget (decodes never wait behind a prompt)."""
+        self.prefill_capped = False
         if not self.prefill_chunk or not self.prefill_waiting:
             return []
         room = self.token_budget - decode_cost
         items = []
+        rows = getattr(self.engine, "max_prefill_rows", None)
         pol = getattr(self.engine, "slo", None)
         # SLO mode: interactive chunks pack first (per-class FIFO behind
         # that, aging promotes starved batch jobs). A batch PrefillJob
@@ -323,6 +331,9 @@ class ContinuousDecodeLoop(threading.Thread):
                 limit = min(limit, int(cap))
             n = min(limit, job.remaining(), room)
             if n > 0:
+                if len(items) == rows:
+                    self.prefill_capped = True
+                    break
                 items.append((job, n))
                 room -= n
         return items
@@ -551,7 +562,7 @@ class ContinuousDecodeLoop(threading.Thread):
     def _run_loop(self):
         while True:
             self.last_pass = time.time()
-            acc = {}                         # this pass's phase seconds
+            acc = {}             # this pass's phase seconds and counts
             with self.cv:
                 if not self.running:
                     break
@@ -566,6 +577,8 @@ class ContinuousDecodeLoop(threading.Thread):
                                                 len(batch))
                         dcost = self._decode_cost(batch)
                         pitems = self._take_prefill_locked(dcost)
+                        if self.prefill_capped:
+                            acc["prefill_row_capped"] = 1
                         self._inflight_prefill = frozenset(
                             id(j) for j, _ in pitems)
                         pwaiting = bool(self.prefill_waiting)
@@ -717,7 +730,8 @@ class DecodeLoopMixin:
             return dict(self.stats)
 
     def note_loop_phases(self, acc: dict):
-        """Add one loop pass's phase seconds (``plan_s``, ``emit_s``)."""
+        """Add one loop pass's phase seconds (``plan_s``, ``emit_s``)
+        and planner counts (``prefill_row_capped``)."""
         with self._stats_lock:
             for k, v in acc.items():
                 self.stats[k] += v

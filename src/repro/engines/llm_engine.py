@@ -84,12 +84,17 @@ def _new_stats() -> dict:
     the tokens it prefilled + those the ``max_len`` clamp cut.
     ``new_shapes``: step calls on a (jitted step, argument shapes) pair
     this engine had not run. ``token_reads``: device-to-host reads of a
-    step's sampled ids, one per step call. ``<phase>_s``: host seconds of each
-    continuous-loop phase (``decode_loop.phase``)."""
+    step's sampled ids, one per step call. Chunked prefill in the loop:
+    ``prefill_passes``, chunk steps that landed a chunk; ``prefill_rows``,
+    chunks landed; ``prefill_row_capped``, passes whose plan the cap on
+    chunks per pass ended with budget room left and a job waiting.
+    ``<phase>_s``: host seconds of each continuous-loop phase
+    (``decode_loop.phase``)."""
     return {"prefill_tokens": 0, "decode_tokens": 0, "calls": 0,
             "decode_iters": 0, "migrations_in": 0, "migrated_blocks": 0,
             "migrate_s": 0.0, "prompt_tokens": 0, "radix_hit_tokens": 0,
-            "new_shapes": 0, "token_reads": 0, "plan_s": 0.0,
+            "new_shapes": 0, "token_reads": 0, "prefill_passes": 0,
+            "prefill_rows": 0, "prefill_row_capped": 0, "plan_s": 0.0,
             "prepare_s": 0.0, "launch_s": 0.0, "wait_s": 0.0,
             "readback_s": 0.0, "emit_s": 0.0}
 
@@ -116,6 +121,9 @@ class LLMEngine(DecodeLoopMixin):
     kind = "llm"
 
     ALLOC_TIMEOUT = 30.0        # prefill backpressure wait (s)
+    # one prefill step pads its chunks to a batch bucket, so the loop
+    # plans at most the largest bucket's rows per pass
+    max_prefill_rows = BUCKETS_B[-1]
 
     def __init__(self, name: str, cfg: ModelConfig, *, max_len: int = 512,
                  seed: int = 0, max_batch: int = 8, max_tokens: int = 1024,
@@ -1117,6 +1125,8 @@ class LLMEngine(DecodeLoopMixin):
         with self._stats_lock:
             self.stats["prefill_tokens"] += sum(len(c) for _, c in items)
             self.stats["calls"] += 1
+            self.stats["prefill_passes"] += 1
+            self.stats["prefill_rows"] += len(items)
             for k, v in acc.items():
                 self.stats[k] += v
 

@@ -6,6 +6,7 @@ speculative-decode composition, and the post-chunk admission re-check."""
 import itertools
 import threading
 import time
+import types
 
 import pytest
 
@@ -247,6 +248,123 @@ def test_token_budget_property():
         eng.stop_decode_loop()
 
     check()
+
+
+# ---------------------------------------------------------------------------
+# Chunks per pass: at most the largest batch bucket
+
+def test_plan_caps_chunks_at_the_row_bound_and_keeps_fifo():
+    """Under the cap a plan is exactly the uncapped plan; past it the
+    plan stops at ``max_prefill_rows`` chunks, flags the cap only when
+    budget room was left, and the rest stay queued in order."""
+    def plan(njobs, rows, budget=144):
+        engine = types.SimpleNamespace(name="e", max_prefill_rows=rows)
+        loop = ContinuousDecodeLoop(engine, max_slots=16, prefill_chunk=128,
+                                    token_budget=budget)
+        loop.prefill_waiting.extend(
+            PrefillJob(f"j{i}", None, list(range(8 + i % 3)))
+            for i in range(njobs))
+        took = [(j.sid, n) for j, n in loop._take_prefill_locked(0)]
+        return took, loop.prefill_capped
+
+    for njobs in (1, 15, 16):
+        assert plan(njobs, 16) == (plan(njobs, None)[0], False)
+    for njobs in (17, 33):
+        took, capped = plan(njobs, 16)
+        assert took == plan(njobs, None)[0][:16] and capped
+    # the budget, not the cap, ended this plan: 16 jobs of 9 tokens fill
+    # 144 exactly, so the 17th had no room
+    took, capped = plan(17, 16, budget=sum(8 + i % 3 for i in range(16)))
+    assert len(took) == 16 and not capped
+
+
+def _forked_prefill(njobs, together):
+    """Prefill ``njobs`` prompts that fork a cached 64-token prefix and
+    bring 8 new tokens each, on an engine with the served chunk (128)
+    and budget (144). ``together``: all queued before the loop plans;
+    else one at a time. Returns each prompt's (pos, next token,
+    next-token logits), the rows of every prefill step call and the
+    counters' growth."""
+    import jax
+    import numpy as np
+
+    from repro.engines.llm_engine import step_logits
+    eng = _engine(paged=True, chunked=True, max_batch=16, block_size=8,
+                  prefill_chunk=128, prefix_cache="radix")
+    prefix = _words(64)
+    eng.op_prefill([{"sid": "prefix", "text": prefix}])
+    tasks = [{"sid": f"s{i}",
+              "text": prefix + " " + " ".join(f"x{i}_{k}" for k in range(8))}
+             for i in range(njobs)]
+    sid_of = {tuple(eng.tok.encode(t["text"])[64:]): t["sid"]
+              for t in tasks}
+    logits_of = jax.jit(step_logits, static_argnums=0)
+    real, calls, out = eng._paged_pstep, [], {}
+
+    def recorded(params, toks, pool, tables, pos, last_idx):
+        lg = np.asarray(logits_of(eng.cfg, params, toks, pool, pos, tables,
+                                  last_idx)[0])
+        rows = [sid_of.get(tuple(np.asarray(r[:8]).tolist())) for r in toks]
+        rows = [sid for sid in rows if sid is not None]
+        calls.append((toks.shape[0], len(rows)))
+        for i, sid in enumerate(rows):
+            out[sid] = lg[i]
+        return real(params, toks, pool, tables, pos, last_idx)
+
+    eng._paged_pstep = recorded
+    before = eng.counters()
+    if together:
+        loop = eng.start_decode_loop()
+        with loop.cv:                   # the loop plans once all are in
+            jobs = [eng.submit_prefill(t) for t in tasks]
+        for job in jobs:
+            job.wait(120)
+    else:
+        for t in tasks:
+            eng.op_prefill([t])
+    after = eng.counters()
+    eng.stop_decode_loop()
+    grew = {k: after[k] - before[k] for k in
+            ("prefill_passes", "prefill_rows", "prefill_row_capped")}
+    seqs = {sid: (eng.states[sid].pos, eng.states[sid].last_token,
+                  out[sid]) for sid in sid_of.values()}
+    assert eng.counters()["radix_hit_tokens"] == 64 * njobs
+    return seqs, calls, grew
+
+
+# Rows of one step and of a one-row step run different matmul shapes,
+# so float32 rounding may differ in the last bits of a logit.
+LOGIT_TOL = 1e-4    # of the logits' RMS
+
+
+@pytest.mark.parametrize("njobs,passes", [(17, 2), (33, 3)])
+def test_more_forked_chunks_than_the_top_bucket_land_over_passes(njobs,
+                                                                 passes):
+    """17 and 33 forked 8-token prompts queued at once fit the token
+    budget (144) in one or two passes but land over 2 and 3: no step
+    carries more than ``BUCKETS_B[-1]`` rows, nothing raises, and each
+    prompt's position, next token and next-token logits equal those of
+    the same prompt prefilled alone. The counters read the plan."""
+    import numpy as np
+
+    from repro.engines.llm_engine import BUCKETS_B
+    top = BUCKETS_B[-1]
+    got, calls, grew = _forked_prefill(njobs, together=True)
+    assert [n for _, n in calls] == [top] * (passes - 1) + \
+        [njobs - top * (passes - 1)]
+    assert all(b <= top for b, _ in calls)
+    assert grew == {"prefill_passes": passes, "prefill_rows": njobs,
+                    "prefill_row_capped": passes - 1}
+    alone, calls1, grew1 = _forked_prefill(njobs, together=False)
+    assert [n for _, n in calls1] == [1] * njobs
+    assert grew1 == {"prefill_passes": njobs, "prefill_rows": njobs,
+                     "prefill_row_capped": 0}
+    assert set(got) == set(alone)
+    for sid, (pos, tok, lg) in got.items():
+        pos1, tok1, lg1 = alone[sid]
+        assert (pos, tok) == (pos1, tok1) == (72, int(np.argmax(lg)))
+        rms = np.sqrt(np.mean(lg1 ** 2))
+        assert np.max(np.abs(lg - lg1)) <= LOGIT_TOL * rms, sid
 
 
 # ---------------------------------------------------------------------------
